@@ -20,7 +20,8 @@ token). This benchmark publishes three numbers per index backend:
 * **modeled HBM bytes/token** — an analytic per-stage model of the traffic
   that differs (intermediate round trips vs in-VMEM residency) on top of
   the shared mandatory reads, priced against the roofline HBM bandwidth
-  (:data:`repro.launch.roofline.HW`). Asserted strictly smaller fused.
+  (:data:`repro.launch.roofline.PEAKS`, the TPU v5e row). Asserted
+  strictly smaller fused.
 
 Wall-clock figures are interpret-mode CPU and indicative only.
 
@@ -42,10 +43,11 @@ from repro.core import estimators as est
 from repro.core import mips
 from repro.kernels import ops as kops
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.roofline import HW
+from repro.launch.roofline import peaks
 
 K = L = 64
 N_PROBE = 8
+MODELED_KIND = "TPU v5 lite"  # the roofline.PEAKS row the bound is priced at
 T = 4  # decode tokens per measured dispatch
 
 
@@ -152,8 +154,9 @@ def _backend_report(report, out, label, kind, emb, h, index, geom,
     bt_u, bt_f = bm["bytes_tok_unfused"], bm["bytes_tok_fused"]
     assert bt_f < bt_u, f"{label}: modeled bytes/token {bt_f} not < {bt_u}"
     # memory-roofline decode rate bound at the modeled traffic
-    tok_s_u = HW["hbm_bw"] / bt_u
-    tok_s_f = HW["hbm_bw"] / bt_f
+    hbm_bw = peaks(MODELED_KIND)["hbm_bw"]
+    tok_s_u = hbm_bw / bt_u
+    tok_s_f = hbm_bw / bt_f
     t_u = timeit(_sample_fn(False), jax.random.key(1), emb, h, index,
                  iters=iters, warmup=1)
     t_f = timeit(_sample_fn(True), jax.random.key(1), emb, h, index,

@@ -29,13 +29,16 @@ def _bench_rows():
 
     import jax
     import jax.numpy as jnp
+    from jax.sharding import AxisType
 
     from benchmarks.common import timeit
     from repro.core.amortized_head import HeadConfig, make_index
     from repro.launch.hlo_analysis import analyze_hlo
     from repro.models.head import dist_head_loss, dist_head_sample
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
     emb = jax.random.normal(jax.random.key(0), (N, D)) / jnp.sqrt(D)
     h = jax.random.normal(jax.random.key(1), (T, D)) * 2.0
     tgt = jax.random.randint(jax.random.key(2), (T,), 0, N)
@@ -114,6 +117,9 @@ def run(report) -> None:
     """Benchmark-suite entry: re-exec with fake host devices (jax in this
     process is already initialized single-device)."""
     env = dict(os.environ)
+    # the child models the mesh on fake host devices; it must never reach
+    # for an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"

@@ -249,7 +249,7 @@ def head_sample(
     """
     cfg = cfg.resolved()
     embf = emb.astype(jnp.float32)[: cfg.n]
-    h = h.astype(jnp.float32)
+    h = est.query_f32(h)
     t = h.shape[0]
 
     if cfg.mode == "exact":

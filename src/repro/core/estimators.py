@@ -67,6 +67,7 @@ __all__ = [
     "dense_gumbel_max",
     "combine_sample_pmax",
     "chunked_map",
+    "query_f32",
 ]
 
 
@@ -79,6 +80,22 @@ __all__ = [
 # lower dtype (HeadConfig.score_dtype) — every reduction over them is
 # explicitly cast up first.
 ESTIMATOR_DTYPE = jnp.float32
+
+
+def query_f32(h: jax.Array) -> jax.Array:
+    """The sampler's queries as f32, holding exactly the values of
+    ``h``'s own dtype.
+
+    A bf16 query cast up to f32 is exact, but XLA is allowed to hand some
+    consumers the f32 value the bf16 one was rounded from (it drops the
+    convert pair as "excess precision") and others the rounded one. On a
+    TPU v5e that split the fused decode: its tail kernel scored the
+    unrounded query, the screen kernel and the unfused path the rounded
+    one, and the engines' samples diverged. ``reduce_precision`` makes the
+    rounding an op of its own, so every stage, XLA or Pallas, scores one
+    query (a no-op for f32 input)."""
+    fi = jnp.finfo(h.dtype)
+    return jax.lax.reduce_precision(h.astype(jnp.float32), fi.nexp, fi.nmant)
 
 
 class LossPartials(NamedTuple):
@@ -319,13 +336,27 @@ def lsh_sampler_logz(
     )
 
 
-def exact_logz(emb: jax.Array, h: jax.Array, n_valid=None) -> jax.Array:
-    """Dense per-token logsumexp over the valid local rows (baseline)."""
+def exact_logz(emb: jax.Array, h: jax.Array, n_valid=None,
+               axis: str | None = None) -> jax.Array:
+    """Dense per-token logsumexp over the valid local rows (baseline).
+
+    ``axis`` (a mesh axis the rows are sharded over, inside shard_map)
+    makes it the logsumexp over every shard's rows: one global max first,
+    so each score is exponentiated against the max a single device would
+    use, and only the sum is split. Combining per-shard logsumexps instead
+    puts every term through a different exp and log, and on a TPU v5e
+    those differ from a one-device run by up to 1e-4."""
     scores = (h @ emb.T).astype(jnp.float32)
     if n_valid is not None:
         ok = jnp.arange(emb.shape[0]) < n_valid
         scores = jnp.where(ok[None, :], scores, -jnp.inf)
-    return jax.nn.logsumexp(scores, axis=-1)
+    if axis is None:
+        return jax.nn.logsumexp(scores, axis=-1)
+    m = jax.lax.pmax(jax.lax.stop_gradient(jnp.max(scores, axis=-1)), axis)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    total = jax.lax.psum(jnp.sum(jnp.exp(scores - m[:, None]), axis=-1),
+                         axis)
+    return jnp.log(total) + m
 
 
 def target_partial(
@@ -352,19 +383,24 @@ def loss_partials(
     n_valid=None,
     score_dtype=jnp.float32,
     use_kernel: bool = False,
+    axis: str | None = None,
 ) -> LossPartials:
     """Shard-local loss partials for one (t, d) token block.
 
     The probe runs on stop-gradient queries; candidate scores are then
     RECOMPUTED through the differentiable gather so ∇(emb, h) flows through
     both strata (the Alg-4 gradient), robust to stale index values.
+
+    ``axis`` (exact mode, inside shard_map): the mesh axis the rows are
+    sharded over; ``log_z`` is then already the global logsumexp
+    (:func:`exact_logz`), which :func:`combine_loss_psum` passes through.
     """
     emb_s = emb.astype(score_dtype)
     h_s = h.astype(score_dtype)
     targets = targets.astype(jnp.int32)
     if mode == "exact":
         return LossPartials(
-            exact_logz(emb_s, h_s, n_valid),
+            exact_logz(emb_s, h_s, n_valid, axis=axis),
             target_partial(emb_s, h_s, targets, n_valid),
         )
     topk = topk_probe(
@@ -405,6 +441,8 @@ def combine_loss_psum(p: LossPartials, mode: str, axis: str) -> jax.Array:
     """
     sg = jax.lax.stop_gradient
     y_t_g = jax.lax.psum(p.y_t, axis)
+    if mode == "exact":  # log_z spans the shards already (loss_partials)
+        return p.log_z - y_t_g
     if mode == "topk_only":
         m = jnp.maximum(jax.lax.pmax(sg(p.log_z), axis), sg(y_t_g))
         z = jax.lax.psum(jnp.exp(p.log_z - m), axis) + jnp.exp(y_t_g - m)
@@ -470,7 +508,7 @@ def local_gumbel_max(
     if m_cap is None:
         m_cap = int(l + 6 * math.sqrt(l) + 8)
     embf = emb.astype(jnp.float32)
-    hf = h.astype(jnp.float32)
+    hf = query_f32(h)
     width = None
     screen = getattr(index, "screen_select", None) if fused else None
     if adaptive and hasattr(index, "topk_adaptive"):
@@ -511,8 +549,11 @@ def local_gumbel_max(
         )
     else:
         def one(kk, tk_ids, tk_vals, kv, hh):
-            score_fn = (
-                lambda ids: embf[jnp.minimum(ids, emb.shape[0] - 1)] @ hh
+            # full f32 precision, as the fused tail kernel scores: on TPU
+            # the default f32 matmul rounds its inputs to bf16
+            score_fn = lambda ids: jnp.dot(  # noqa: E731
+                embf[jnp.minimum(ids, emb.shape[0] - 1)], hh,
+                precision=jax.lax.Precision.HIGHEST,
             )
             return sample_fixed_b(
                 kk, TopK(tk_ids, tk_vals), nv, score_fn, l=l, m_cap=m_cap,

@@ -354,7 +354,9 @@ class IVFPQIndex:
         lut_vals, pos = jax.lax.top_k(scores, r)
         cand = jnp.take_along_axis(ids, pos, axis=1)  # (b, r)
         rows = dbf[jnp.maximum(cand, 0)].astype(jnp.float32)  # (b, r, d)
-        exact = jnp.einsum("brd,bd->br", rows, qf)
+        exact = jnp.einsum(  # full f32, as the fused re-rank kernel
+            "brd,bd->br", rows, qf, precision=jax.lax.Precision.HIGHEST
+        )
         exact = jnp.where(
             (cand >= 0) & ~jnp.isneginf(lut_vals), exact, -jnp.inf
         )

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.gumbel import TopK
 from repro.core.mips import base
 from repro.core.mips.adaptive import AdaptiveTopK
@@ -87,7 +86,7 @@ def _refresh_program(config, mesh, axis: str):
 
     def run(db, state):
         specs = jax.tree.map(lambda x: _leaf_spec(axis, x), state)
-        fn = shard_map(
+        fn = jax.shard_map(
             refresh_loc,
             mesh=mesh,
             in_specs=(P(axis, *((None,) * (db.ndim - 1))), specs),
@@ -156,7 +155,7 @@ class ShardedIndex:
                 jax.ShapeDtypeStruct((n_local,) + db.shape[1:], db.dtype),
             )
             out_specs = jax.tree.map(lambda s: _leaf_spec(axis, s), shapes)
-            fn = shard_map(
+            fn = jax.shard_map(
                 build_loc,
                 mesh=mesh,
                 in_specs=(P(axis, *((None,) * (db.ndim - 1))),),
@@ -243,7 +242,7 @@ class ShardedIndex:
             v, pos = jax.lax.top_k(av, k)
             return TopK(jnp.take_along_axis(ag, pos, axis=1), v)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(), self.state_specs()),
@@ -302,7 +301,7 @@ class ShardedIndex:
                 aw.max(axis=0), ac.all(axis=0),
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(P(), self.state_specs()),

@@ -26,6 +26,7 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels import decode_fused as _decode_fused
 from repro.kernels.flash_decode import flash_decode as _flash_decode
+from repro.kernels.flash_decode import flash_prefill as _flash_prefill
 from repro.kernels.fused_estimator import fused_estimator as _fused_estimator
 from repro.kernels.ivf_gather_score import ivf_gather_score as _ivf_gather_score
 from repro.kernels.pq_lut_score import pq_lut_score as _pq_lut_score
@@ -38,6 +39,7 @@ __all__ = [
     "pq_lut_score",
     "fused_estimator",
     "flash_decode",
+    "flash_prefill",
     "ivf_screen_select",
     "pq_screen_select",
     "rerank_select",
@@ -134,6 +136,18 @@ def flash_decode(q, k_cache, v_cache, lengths, *, s_block: int = 512):
     return _flash_decode(
         q, k_cache, v_cache, lengths, s_block=s_block,
         interpret=resolve_interpret(),
+    )
+
+
+def flash_prefill(q, k_cache, v_cache, *, s_block: int = 512):
+    if OPAQUE_STUBS:
+        return _stub(
+            "flash_prefill",
+            jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            q, k_cache, v_cache,
+        )
+    return _flash_prefill(
+        q, k_cache, v_cache, s_block=s_block, interpret=resolve_interpret(),
     )
 
 

@@ -16,8 +16,11 @@ gathers by vector index don't vectorize on TPU, one-hot × table does. The
 one-hot lives only in VMEM/registers, one subspace at a time, so peak
 VMEM is ``cap·ksub`` floats regardless of ``m_sub``.
 
-Grid: ``(b, n_probe)``; the per-query ``(m_sub, ksub)`` LUT block stays
-resident across a query's probe steps.
+Grid: ``(b, n_probe)``; the per-query ``(m_sub, ksub)`` LUT block and the
+``(n_probe, cap)`` score block stay resident across a query's probe steps,
+and scores come out lane-major as one ``(1, cap)`` row per probe (the TPU
+tiling rule: a block's last two dims are the array's own or (8, 128)-
+aligned).
 """
 from __future__ import annotations
 
@@ -33,28 +36,34 @@ __all__ = ["pq_lut_score", "lut_tile_scores"]
 
 def lut_tile_scores(codes: jax.Array, lut: jax.Array) -> jax.Array:
     """Score one ``(cap, m_sub)`` code tile against one ``(m_sub, ksub)``
-    LUT: ``out[c] = Σ_m lut[m, codes[c, m]]`` as f32.
+    LUT: ``out[0, c] = Σ_m lut[m, codes[c, m]]`` as a ``(1, cap)`` f32 row.
 
     Shared between this kernel and the fused decode screen
     (:mod:`repro.kernels.decode_fused`) so both paths are the *same
     floating-point program* — the fused/unfused bitwise-parity guarantee
-    rests on it. Per subspace, a ``(cap, ksub)`` one-hot of the codes
-    matmuls the subspace's LUT row — gathers by vector index don't
-    vectorize on TPU, one-hot × table does.
+    rests on it. Per subspace, the subspace's LUT row contracts a
+    ``(cap, ksub)`` one-hot of the codes at full f32 precision — gathers by
+    vector index don't vectorize on TPU, one-hot × table does, and each
+    output sums exactly one nonzero term.
     """
     codes = codes.astype(jnp.int32)  # (cap, m_sub)
     cap = codes.shape[0]
     m_sub, ksub = lut.shape
     cols = jax.lax.broadcasted_iota(jnp.int32, (cap, ksub), 1)
-    acc = jnp.zeros((cap,), jnp.float32)
-    for mi in range(m_sub):  # static unroll: one MXU matvec per subspace
-        onehot = (codes[:, mi][:, None] == cols).astype(jnp.float32)
-        acc += jnp.dot(onehot, lut[mi], preferred_element_type=jnp.float32)
+    acc = jnp.zeros((1, cap), jnp.float32)
+    for mi in range(m_sub):  # static unroll: one MXU pass per subspace
+        onehot = (codes[:, mi:mi + 1] == cols).astype(jnp.float32)
+        acc += jax.lax.dot_general(
+            lut[mi:mi + 1], onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
     return acc
 
 
 def _kernel(probe_ref, codes_ref, lut_ref, out_ref):
-    out_ref[0, 0, :] = lut_tile_scores(codes_ref[0], lut_ref[0])
+    j = pl.program_id(1)
+    out_ref[pl.ds(j, 1), :] = lut_tile_scores(codes_ref[...], lut_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -79,14 +88,16 @@ def pq_lut_score(
             in_specs=[
                 # code tile chosen by the prefetched probe ids
                 pl.BlockSpec(
-                    (1, cap, m_sub), lambda i, j, probe: (probe[i, j], 0, 0)
+                    (None, cap, m_sub),
+                    lambda i, j, probe: (probe[i, j], 0, 0),
                 ),
                 pl.BlockSpec(
-                    (1, m_sub, lut.shape[2]), lambda i, j, probe: (i, 0, 0)
+                    (None, m_sub, lut.shape[2]),
+                    lambda i, j, probe: (i, 0, 0),
                 ),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, cap), lambda i, j, probe: (i, j, 0)
+                (None, n_probe, cap), lambda i, j, probe: (i, 0, 0)
             ),
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_probe, cap), jnp.float32),

@@ -133,18 +133,18 @@ def tail_gather_argmax_ref(emb, pos, m_used, pert_s, s_ids, heights, h):
 def flash_decode_ref(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array
 ) -> jax.Array:
-    """(B,Hq,hd), (B,S,Hkv,hd) x2, (B,) -> (B,Hq,hd)."""
+    """(B,Hq,hd), head-major (B,Hkv,S,hd) x2, (B,) -> (B,Hq,hd)."""
     b, hq, hd = q.shape
-    _, s, hkv, _ = k_cache.shape
+    _, hkv, s, _ = k_cache.shape
     g = hq // hkv
     qf = q.astype(jnp.float32)
     kf = k_cache.astype(jnp.float32)
     vf = v_cache.astype(jnp.float32)
     # expand KV heads to query heads
-    kf = jnp.repeat(kf, g, axis=2)  # (B, S, Hq, hd)
-    vf = jnp.repeat(vf, g, axis=2)
-    scores = jnp.einsum("bhd,bshd->bhs", qf, kf) / (hd**0.5)
+    kf = jnp.repeat(kf, g, axis=1)  # (B, Hq, S, hd)
+    vf = jnp.repeat(vf, g, axis=1)
+    scores = jnp.einsum("bhd,bhsd->bhs", qf, kf) / (hd**0.5)
     mask = jnp.arange(s)[None, None, :] < lengths[:, None, None]
     scores = jnp.where(mask, scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhs,bshd->bhd", p, vf)
+    return jnp.einsum("bhs,bhsd->bhd", p, vf)

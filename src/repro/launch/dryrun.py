@@ -34,6 +34,11 @@ from repro.models.model import Model, active_param_count
 from repro.optim import adamw
 
 
+# the chip the production meshes are built from (v5e-256 pods): its
+# roofline.PEAKS row prices every cell
+MODELED_KIND = "TPU v5 lite"
+
+
 def _key_sds():
     return jax.eval_shape(lambda: jax.random.key(0))
 
@@ -144,12 +149,13 @@ def run_cell(arch: str, shape: str, multi_pod: bool, tcfg, verbose=True,
         n_active = active_param_count(cfg)
         model_flops = (6 if kind == "train" else 2) * n_active * n_tokens
         rep = roofline.analyze(
-            arch, shape, mesh_name, n_dev, compiled, model_flops
+            arch, shape, mesh_name, n_dev, compiled, model_flops,
+            MODELED_KIND,
         )
         ms = rep.mem_stats
         out = {
             "arch": arch, "shape": shape, "mesh": mesh_name, "kind": kind,
-            "status": "ok",
+            "device_kind": rep.device_kind, "status": "ok",
             "flops_per_device": rep.flops_per_device,
             "bytes_per_device": rep.bytes_per_device,
             "coll_bytes_per_device": rep.coll_bytes_per_device,
@@ -208,6 +214,8 @@ def main() -> int:
     default_accum = {"mixtral-8x22b": 8, "qwen3-moe-30b-a3b": 4,
                      "granite-8b": 2, "recurrentgemma-9b": 2}
 
+    print(f"dry-run: roofline priced at {MODELED_KIND!r} peaks "
+          f"{roofline.peaks(MODELED_KIND)}", flush=True)
     results = []
     fails = 0
     for arch in archs:

@@ -19,13 +19,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_train_mesh(dp: int = 1, tp: int = 1):
@@ -43,7 +43,8 @@ def make_train_mesh(dp: int = 1, tp: int = 1):
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={n})"
         )
     return jax.sharding.Mesh(
-        np.asarray(devs[:n]).reshape(dp, tp), ("data", "model")
+        np.asarray(devs[:n]).reshape(dp, tp), ("data", "model"),
+        axis_types=(AxisType.Auto, AxisType.Auto),
     )
 
 
@@ -159,7 +160,7 @@ def cache_shardings(cache_shapes: Any, mesh, cfg, paged: bool = False) -> Any:
     head/width dim over "model" when divisible (decode TP).
 
     ``paged``: the k/v leaves are the shared block pool ``(layers,
-    n_blocks, block_len, KV, hd)`` — axis 1 is a *physical block id*, not
+    n_blocks, KV, block_len, hd)`` — axis 1 is a *physical block id*, not
     a batch dim, and page-table gathers index it from every data row, so
     it must stay replicated over ("pod","data") (only KV-head TP applies).
     """
@@ -175,12 +176,12 @@ def cache_shardings(cache_shapes: Any, mesh, cfg, paged: bool = False) -> Any:
             if fa and _dim_ok(shape[1], mesh, fa):
                 spec[1] = fa  # batch
         if name in ("k", "v") and len(shape) == 5:
-            # dense (layers, B, S, KV, hd) / pool (layers, nb, bl, KV, hd):
-            # prefer KV-head TP, else (dense only) seq TP
-            if _dim_ok(shape[3], mesh, "model"):
-                spec[3] = "model"
-            elif not pool_leaf and _dim_ok(shape[2], mesh, "model"):
+            # head-major dense (layers, B, KV, S, hd) / pool (layers, nb,
+            # KV, bl, hd): prefer KV-head TP, else (dense only) seq TP
+            if _dim_ok(shape[2], mesh, "model"):
                 spec[2] = "model"
+            elif not pool_leaf and _dim_ok(shape[3], mesh, "model"):
+                spec[3] = "model"
         elif name == "state" and len(shape) >= 3:
             if _dim_ok(shape[2], mesh, "model"):
                 spec[2] = "model"

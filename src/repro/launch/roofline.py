@@ -11,8 +11,8 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 op we recover *operand* bytes from the (per-device) result shape and the
 replica-group size printed on the same line.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment).
+Hardware constants come from :data:`PEAKS`, one row per ``device_kind``
+as JAX reports it; a kind without a row is an error, never a default.
 """
 from __future__ import annotations
 
@@ -20,9 +20,26 @@ import dataclasses
 import re
 from typing import Any
 
-__all__ = ["HW", "RooflineReport", "collective_bytes", "analyze"]
+__all__ = ["PEAKS", "peaks", "RooflineReport", "collective_bytes", "analyze"]
 
-HW = dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9)
+# Published per-chip peaks by ``jax.devices()[0].device_kind``. TPU v5e
+# (JAX kind "TPU v5 lite"), Google Cloud documentation "TPU v5e": 197
+# TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect —
+# ici_bw is that per chip over its 4 links, 50 GB/s each.
+PEAKS = {
+    "TPU v5 lite": dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The :data:`PEAKS` row of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -93,6 +110,7 @@ def collective_bytes(hlo_text: str) -> dict[str, Any]:
 
 @dataclasses.dataclass
 class RooflineReport:
+    device_kind: str
     arch: str
     shape: str
     mesh: str
@@ -126,7 +144,10 @@ def analyze(
     n_devices: int,
     compiled,
     model_flops: float,
+    device_kind: str,
 ) -> RooflineReport:
+    """Roofline terms of ``compiled`` priced at ``device_kind``'s peaks."""
+    hw = peaks(device_kind)
     # loop-aware HLO cost model (launch/hlo_analysis.py): XLA's own
     # cost_analysis() counts while (lax.scan) bodies once, undercounting a
     # scanned 56-layer trunk ~56x. Validated exact on known programs.
@@ -142,9 +163,9 @@ def analyze(
         "counts": hc.coll_counts,
         "xla_once_counted": collective_bytes(text)["total"],
     }
-    t_c = flops / HW["peak_flops"]
-    t_m = byts / HW["hbm_bw"]
-    t_x = coll["total"] / HW["ici_bw"]
+    t_c = flops / hw["peak_flops"]
+    t_m = byts / hw["hbm_bw"]
+    t_x = coll["total"] / hw["ici_bw"]
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     ms = compiled.memory_analysis()
@@ -155,6 +176,7 @@ def analyze(
         "alias_gb": ms.alias_size_in_bytes / 2**30,
     }
     return RooflineReport(
+        device_kind=device_kind,
         arch=arch,
         shape=shape,
         mesh=mesh_name,

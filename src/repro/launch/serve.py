@@ -3,6 +3,10 @@ lazy-Gumbel sampler.
 
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --smoke \
       --requests 16 --new-tokens 32 --decode-window 8
+
+:func:`serve` is the launcher without its command line: it takes the
+parsed arguments (:func:`parse_args`) and returns the stats dict that
+``main`` prints, plus each request's decoded tokens.
 """
 from __future__ import annotations
 
@@ -13,11 +17,12 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serve.server import ServeConfig, Server
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -81,8 +86,22 @@ def main() -> None:
     ap.add_argument("--probe-router", default="",
                     help="adaptive stage router: 'fit' trains at startup, "
                          "else a router.npz path (repro.models.router)")
-    args = ap.parse_args()
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
+    ap.add_argument("--count-kernels", action="store_true",
+                    help="report the Pallas kernel calls (tpu_custom_call) "
+                         "in the compiled decode program")
+    return ap.parse_args(argv)
 
+
+def serve(args: argparse.Namespace, params=None) -> dict:
+    """Build the model and server ``args`` describe, serve
+    ``args.requests`` seeded random prompts, and return the stats dict.
+
+    ``params`` (optional) reuses weights already on the device; they must
+    be the model's params for the configuration ``args`` selects. The
+    dict's ``tokens`` holds each request's decoded ids, in request order.
+    """
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     if not cfg.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode serving")
@@ -103,8 +122,9 @@ def main() -> None:
             head_n_probe_max=args.n_probe_max,
         )
     model = Model(cfg)
-    params = model.init(jax.random.key(0))
-    rng = np.random.default_rng(0)
+    if params is None:
+        params = model.init(jax.random.key(args.seed))
+    rng = np.random.default_rng(args.seed)
     prompts = [
         list(rng.integers(0, cfg.vocab, size=rng.integers(4, 12)))
         for _ in range(args.requests)
@@ -121,7 +141,7 @@ def main() -> None:
     results = server.run(prompts)
     toks = sum(len(r.tokens) for r in results)
     st = server.stats
-    print(json.dumps({
+    stats = {
         "requests": len(results),
         "decoded_tokens": toks,
         "tokens_per_s": round(toks / st["wall_s"], 1),
@@ -152,7 +172,22 @@ def main() -> None:
             str(k): v
             for k, v in sorted(st["probe_width_hist"].items())
         },
-    }, indent=1))
+        "statuses": [r.status for r in results],
+        "tokens": [list(map(int, r.tokens)) for r in results],
+    }
+    if args.count_kernels:
+        stats["decode_kernel_calls"] = server.decode_program_text().count(
+            "tpu_custom_call"
+        )
+    return stats
+
+
+def main() -> None:
+    enable_compile_cache()
+    stats = serve(parse_args())
+    stats.pop("tokens")
+    stats.pop("statuses")
+    print(json.dumps(stats, indent=1))
 
 
 if __name__ == "__main__":

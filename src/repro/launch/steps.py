@@ -16,7 +16,7 @@ from repro.optim import adamw
 __all__ = ["TrainConfig", "make_train_step", "make_train_loop_step",
            "make_serve_step",
            "make_prefill_step", "make_encode_step", "slot_keys",
-           "make_reference_serve_step", "make_decode_loop_step",
+           "make_decode_loop_step",
            "make_prefill_into_cache_step"]
 
 
@@ -73,6 +73,10 @@ def make_train_step(model: Model, tcfg: TrainConfig):
                 g_acc, l_acc = carry
                 mb, kk = xs
                 (l, m), g = grad_fn(params, mb, kk, index)
+                # the barrier keeps XLA from folding the accumulate into the
+                # gradient's own scatter-add / matmul, which would re-order
+                # the sum away from the host loop's (g_acc + g) per step
+                g = jax.lax.optimization_barrier(g)
                 # fp32 accumulators: bf16 sums would be order-dependent at
                 # the magnitudes the optimizer cares about
                 g_acc = jax.tree.map(
@@ -216,15 +220,22 @@ def make_decode_loop_step(model: Model, window: int, eos_id: int,
                           max_seq: int, strict: bool = False,
                           paged: bool = False):
     """Fused multi-token decode: ``decode_loop(params, cache, state,
-    base_key, index=None, router=None) -> (cache, state, tokens (T,B),
-    ok (T,B), emitted (T,B), widths (T,B))``.
+    base_key, n, index=None, router=None) -> (cache, state, tokens (T,B),
+    ok (T,B), emitted (T,B), widths (T,B))`` with ``T = window``.
+
+    ``n`` (a traced int32 scalar, ``1 <= n <= window``) is the number of
+    tokens this dispatch decodes; rows ``n..T-1`` of the outputs are
+    not emitted. The loop's trip count is an operand, so one compiled
+    loop body serves every window size: the scheduler's shorter windows
+    and the single-token reference loop (``engine="reference"``, ``n=1``)
+    run the very program the full window runs.
 
     ``widths`` is the per-token effective probe width under the head's
     certificate-gated adaptive probe (−1 on fixed-width paths); the engine
     bins emitted slots' widths into ``Server.stats["probe_width_hist"]``.
     ``router`` optionally carries a ProbeRouter pytree into each step.
 
-    A ``lax.scan`` decodes ``window`` tokens per dispatch with per-slot
+    A while loop decodes ``n`` tokens per dispatch with per-slot
     active masks and on-device EOS/length-budget detection — amortizing
     dispatch + host-sync overhead ``window``-fold. Slots that finish
     mid-window stop emitting (and stop perturbing their state) on device;
@@ -244,9 +255,18 @@ def make_decode_loop_step(model: Model, window: int, eos_id: int,
     decode writes must be dropped on device.
     """
 
-    def decode_loop(params, cache, state, base_key, index=None, router=None):
-        def body(carry, _):
-            cache, state = carry
+    def decode_loop(params, cache, state, base_key, n, index=None,
+                    router=None):
+        b = state["ids"].shape[0]
+        outs = (
+            jnp.zeros((window, b), jnp.int32),  # tokens
+            jnp.zeros((window, b), bool),  # ok
+            jnp.zeros((window, b), bool),  # emitted
+            jnp.full((window, b), -1, jnp.int32),  # widths
+        )
+
+        def body(t, carry):
+            cache, state, outs = carry
             keys = slot_keys(base_key, state["rid"], state["pos"])
             nxt, ok, cache, width = model.decode_step(
                 params, cache, state["ids"], state["pos"], None, index=index,
@@ -256,10 +276,16 @@ def make_decode_loop_step(model: Model, window: int, eos_id: int,
                 write_mask=state["active"] if paged else None,
             )
             state, emitted = _advance(state, nxt, eos_id, max_seq)
-            return (cache, state), (state["ids"], ok, emitted, width)
+            outs = tuple(
+                o.at[t].set(v) for o, v in
+                zip(outs, (state["ids"], ok, emitted, width))
+            )
+            return cache, state, outs
 
-        (cache, state), (toks, oks, emitted, widths) = jax.lax.scan(
-            body, (cache, state), None, length=window
+        # traced bounds: a while loop, never unrolled or specialised to n
+        cache, state, (toks, oks, emitted, widths) = jax.lax.fori_loop(
+            jnp.int32(0), jnp.asarray(n, jnp.int32), body,
+            (cache, state, outs),
         )
         return cache, state, toks, oks, emitted, widths
 
@@ -317,25 +343,6 @@ def make_prefill_into_cache_step(model: Model, max_seq: int, eos_id: int,
         return cache, new_state, nxt, ok
 
     return prefill_admit
-
-
-def make_reference_serve_step(model: Model, strict: bool = False):
-    """Single-token serve step with engine-compatible key derivation:
-    ``serve_step(params, cache, ids, pos, rids, base_key, index=None,
-    router=None) -> (next_ids, ok, cache, pos+1, width)``. This is the
-    teacher-forced comparator the engine is validated against (same
-    samples, one dispatch per token)."""
-
-    def serve_step(params, cache, ids, pos, rids, base_key, index=None,
-                   router=None, pages=None, write_mask=None):
-        keys = slot_keys(base_key, rids, pos)
-        nxt, ok, cache, width = model.decode_step(
-            params, cache, ids, pos, None, index=index, keys=keys,
-            strict=strict, router=router, pages=pages, write_mask=write_mask,
-        )
-        return nxt, ok, cache, pos + 1, width
-
-    return serve_step
 
 
 def make_prefill_step(model: Model, max_seq: int):

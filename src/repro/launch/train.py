@@ -15,12 +15,14 @@ import argparse
 import json
 
 from repro.configs import ARCHS, get, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import TrainConfig
 from repro.optim.adamw import OptConfig
 from repro.train.trainer import RunConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true",

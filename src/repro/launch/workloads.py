@@ -28,6 +28,7 @@ import numpy as np
 from repro.configs import ARCHS, get, get_smoke
 from repro.core import estimators as est
 from repro.core import mips
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.workloads import dknn, structured
 
@@ -176,6 +177,7 @@ def run_estimator(args) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 

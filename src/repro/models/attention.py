@@ -188,6 +188,8 @@ def prefill(
     window: int | None = None,
     prefix: int = 0,
     lengths: jax.Array | None = None,  # (B,) valid prompt lengths
+    use_kernel: bool | None = None,  # None: kernel on TPU, XLA on CPU
+    mesh=None,  # device mesh the activations are sharded over (kernel path)
 ) -> tuple[jax.Array, dict]:
     """Forward + KV-cache build. Returns (out, cache).
 
@@ -199,6 +201,16 @@ def prefill(
     slots with no valid position stay zero (masked by the decode-side
     ``lengths`` window anyway). Causality keeps pad queries from affecting
     valid outputs: pads sit strictly after every valid position.
+
+    The cache comes out head-major, ``(B, KV, s_c, hd)`` (see init_cache).
+
+    Where decode runs the flash_decode kernel (``use_kernel``, by default
+    on the chip), a causal prompt that fits the ring attends through its
+    prompt-side twin, ``flash_prefill``: each position reads only its own
+    prefix of the ring built here and gets the output decode computes for
+    it, bit for bit. So prefill leaves the cache a token-by-token decode
+    would, and the serving engines' tokens agree. Prefix-LM, wrapping
+    rings and the CPU take blockwise attention.
     """
     b, l, d = x.shape
     dt = x.dtype
@@ -209,10 +221,6 @@ def prefill(
     if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    out = blockwise_attention(
-        q, k, v, causal=cfg.causal and not cfg.encoder_only, window=win,
-        prefix=prefix,
-    )
     s_c = min(win, max_seq) if win else max_seq
     shape = (b, s_c, cfg.n_kv_heads, cfg.head_dim)
     if lengths is not None:
@@ -234,19 +242,34 @@ def prefill(
         slots = kept % s_c
         ck = jnp.zeros(shape, dt).at[:, slots].set(k[:, l - s_c :])
         cv = jnp.zeros(shape, dt).at[:, slots].set(v[:, l - s_c :])
-    cache = {"k": ck, "v": cv}
+    cache = {"k": ck.transpose(0, 2, 1, 3), "v": cv.transpose(0, 2, 1, 3)}
+    from repro.kernels import ops as kops
+
+    causal = cfg.causal and not cfg.encoder_only
+    if use_kernel is None:
+        use_kernel = not kops.resolve_interpret()
+    if use_kernel and causal and not prefix and l <= s_c:
+        out = _sharded(kops.flash_prefill, mesh, q, cache["k"], cache["v"])
+        out = out.astype(dt)
+    else:
+        out = blockwise_attention(q, k, v, causal=causal, window=win,
+                                  prefix=prefix)
     return out.reshape(b, l, cfg.d_attn) @ p["wo"].astype(dt), cache
 
 
 def init_cache(
     cfg: ArchConfig, batch: int, max_seq: int, dtype, window: int | None = None
 ) -> dict:
-    """``window`` overrides cfg.window (griffin layers pass local_window) so
+    """Per-slot KV rings, head-major ``(batch, KV, s_c, hd)``: each KV
+    head's positions are contiguous, which is how the flash_decode kernel
+    reads them, in place.
+
+    ``window`` overrides cfg.window (griffin layers pass local_window) so
     the decode ring size matches what prefill() builds for the same layer —
     and so the ring itself enforces the sliding window at decode time."""
     win = cfg.window if window is None else window
     s_c = min(win, max_seq) if win else max_seq
-    shape = (batch, s_c, cfg.n_kv_heads, cfg.head_dim)
+    shape = (batch, cfg.n_kv_heads, s_c, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -258,8 +281,9 @@ def init_pool(
     :func:`decode`) maps each slot's ring pages onto physical blocks. One
     physical block id addresses the same block slice in every layer (the
     pool leaf carries the layer-stack axis), so one allocation covers the
-    whole trunk."""
-    shape = (n_blocks, block_len, cfg.n_kv_heads, cfg.head_dim)
+    whole trunk. Blocks are head-major, ``(KV, block_len, hd)``, like the
+    dense rings."""
+    shape = (n_blocks, cfg.n_kv_heads, block_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -284,13 +308,14 @@ def decode(
     use_kernel: bool | None = None,  # None: kernel on TPU, XLA ref on CPU
     pages: jax.Array | None = None,  # (B, n_pages) physical block per page
     write_mask: jax.Array | None = None,  # (B,) rows allowed to write KV
+    mesh=None,  # device mesh the caches are sharded over (kernel path)
 ) -> tuple[jax.Array, dict]:
     """Single-token decode against a per-slot KV ring OR a paged pool.
 
-    Dense (``pages=None``): ``cache`` leaves are ``(B, s_c, KV, hd)`` rings
+    Dense (``pages=None``): ``cache`` leaves are ``(B, KV, s_c, hd)`` rings
     owned by their slot; the new token writes ring slot ``pos % s_c``.
 
-    Paged: ``cache`` leaves are the shared ``(n_blocks, block_len, KV,
+    Paged: ``cache`` leaves are the shared ``(n_blocks, KV, block_len,
     hd)`` pool and ``pages[b, i]`` names the physical block behind slot
     ``b``'s i-th ring page — ring placement becomes page-table arithmetic
     (page ``(pos % s_c) // block_len``, offset ``(pos % s_c) % block_len``
@@ -306,9 +331,9 @@ def decode(
     b, _, d = x.shape
     dt = x.dtype
     if pages is None:
-        s_c = cache["k"].shape[1]
+        s_c = cache["k"].shape[2]
     else:
-        n_blocks, block_len = cache["k"].shape[:2]
+        n_blocks, block_len = cache["k"].shape[0], cache["k"].shape[2]
         s_c = pages.shape[1] * block_len
     q = (x @ p["wq"].astype(dt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
     k = (x @ p["wk"].astype(dt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
@@ -322,8 +347,8 @@ def decode(
     ar = jnp.arange(b)
     if pages is None:
         cache = {
-            "k": cache["k"].at[ar, slot].set(k[:, 0]),
-            "v": cache["v"].at[ar, slot].set(v[:, 0]),
+            "k": cache["k"].at[ar, :, slot].set(k[:, 0]),
+            "v": cache["v"].at[ar, :, slot].set(v[:, 0]),
         }
         k_view, v_view = cache["k"], cache["v"]
     else:
@@ -334,13 +359,17 @@ def decode(
             phys = jnp.where(write_mask, phys, n_blocks)  # OOB -> dropped
         off = slot % block_len
         cache = {
-            "k": cache["k"].at[phys, off].set(k[:, 0]),
-            "v": cache["v"].at[phys, off].set(v[:, 0]),
+            "k": cache["k"].at[phys, :, off].set(k[:, 0]),
+            "v": cache["v"].at[phys, :, off].set(v[:, 0]),
         }
-        vshape = (b, s_c, cfg.n_kv_heads, cfg.head_dim)
-        # gather the slot's ring view (sentinel pages clamp; masked below)
-        k_view = cache["k"][pages].reshape(vshape)
-        v_view = cache["v"][pages].reshape(vshape)
+
+        def view(pool):
+            # gather the slot's ring view (sentinel pages clamp; masked
+            # below): (B, n_pages, KV, block_len, hd) -> (B, KV, s_c, hd)
+            g = pool[pages].transpose(0, 2, 1, 3, 4)
+            return g.reshape(b, cfg.n_kv_heads, s_c, cfg.head_dim)
+
+        k_view, v_view = view(cache["k"]), view(cache["v"])
     lengths = jnp.minimum(pos + 1, s_c).astype(jnp.int32)
     from repro.kernels import ops as kops
 
@@ -349,10 +378,42 @@ def decode(
         # the kernel is exercised explicitly by tests/test_kernels.py
         use_kernel = not kops.resolve_interpret()
     if use_kernel:
-        o = kops.flash_decode(q[:, 0], k_view, v_view, lengths)
+        o = _sharded(kops.flash_decode, mesh, q[:, 0], k_view, v_view,
+                     lengths)
     else:
         from repro.kernels import ref as kref
 
         o = kref.flash_decode_ref(q[:, 0], k_view, v_view, lengths)
     out = o.astype(dt).reshape(b, 1, cfg.d_attn) @ p["wo"].astype(dt)
     return out, cache
+
+
+def _sharded(kernel, mesh, q, k_view, v_view, *lengths):
+    """An attention kernel — queries ``(B, ..., Hq, hd)`` against
+    head-major ``(B, KV, S, hd)`` caches, optional ``(B,)`` lengths; on a
+    mesh, once per shard inside ``shard_map``: the compiler cannot
+    partition a Pallas kernel. Each shard holds whole GQA groups: KV heads
+    (and their query heads) split over "model" when they divide it, the
+    batch over the data axes when it divides them; anything else stays
+    replicated."""
+    if mesh is None:
+        return kernel(q, k_view, v_view, *lengths)
+    from jax.sharding import PartitionSpec as P
+
+    data = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+    n_data = 1
+    for a in data:
+        n_data *= mesh.shape[a]
+    bs = data if data and q.shape[0] % n_data == 0 else None
+    hs = (
+        "model"
+        if "model" in mesh.shape and k_view.shape[1] % mesh.shape["model"] == 0
+        else None
+    )
+    qs = P(bs, *([None] * (q.ndim - 3)), hs, None)
+    kv = P(bs, hs, None, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(qs, kv, kv) + (P(bs),) * len(lengths),
+        out_specs=qs, check_vma=False,
+    )(q, k_view, v_view, *lengths)

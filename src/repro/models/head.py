@@ -12,6 +12,9 @@ shard plumbing and the O(1)-per-token collectives:
           target logit via masked psum — the stratified sum of per-shard
           Algorithm-3 estimators, still exactly unbiased (Thm 3.4 per
           shard). See :func:`repro.core.estimators.combine_loss_psum`.
+          Exact mode takes the pmax of the raw scores first and psums
+          the exponentials (:func:`repro.core.estimators.exact_logz`), so
+          it repeats the one-device arithmetic term by term.
 * sample: the global argmax of per-shard lazy-Gumbel maxima IS an exact
           global sample; exactness certificates compose via a pmin
           (:func:`repro.core.estimators.combine_sample_pmax`). Collective
@@ -28,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import estimators as est
 from repro.core.amortized_head import HeadConfig
 
@@ -93,6 +95,7 @@ def dist_head_loss(
                 kk, emb_loc, hc, tc, mode=cfg.mode, k=k_loc, l=l_loc,
                 index=index_loc, n_valid=n_valid, score_dtype=cfg.score_dt,
                 use_kernel=cfg.use_kernel,
+                axis="model" if cfg.mode == "exact" else None,
             )
 
         parts = est.chunked_map(one_chunk, cfg.chunk, key, h_loc, tgt_local)
@@ -100,7 +103,7 @@ def dist_head_loss(
 
     idx_args, idx_specs = _index_args(index)
     tok_ax = _token_spec(mesh, h.shape[0])
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P("model", None), P(tok_ax, None), P(tok_ax), P(),
@@ -197,7 +200,7 @@ def dist_head_sample(
     if use_router:
         rt_args = (router,)
         rt_specs = (P(),)  # replicated: every shard routes its local probe
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P("model", None), P(tok_ax, None), P(),

@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.config import ArchConfig
 from repro.models.layers import dense_init
 
@@ -102,7 +101,7 @@ def forward_dist(
             "w3": P(None, None, "model"),
             "w2": P(None, "model", None),
         }
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(p_specs, P(tok_ax, None)),

@@ -269,7 +269,7 @@ def _apply_block_prefill(
         win = _layer_window(cfg)
         mix, cache = attention.prefill(
             p["mix"], cfg, x, positions, max_seq, window=win, prefix=prefix,
-            lengths=lengths,
+            lengths=lengths, mesh=mesh,
         )
     else:
         mix, cache = rglru.forward(p["mix"], cfg, x, return_cache=True,
@@ -339,8 +339,8 @@ def insert_cache_slots(
     partially-filled admission batch.
 
     Paged layout (``pages`` given, requires ``cfg``): attn KV leaves of
-    ``full`` are the shared pool ``(layers, n_blocks, block_len, KV, hd)``;
-    the prefill-built ring ``(layers, Bn, s_c, KV, hd)`` is re-cut into
+    ``full`` are the shared pool ``(layers, n_blocks, KV, block_len, hd)``;
+    the prefill-built ring ``(layers, Bn, KV, s_c, hd)`` is re-cut into
     pages and scattered to each admitted row's physical blocks
     (``pages[b, i]``, sentinel ``n_blocks`` for unallocated/pad rows —
     dropped). Non-attn leaves stay slot-scattered as in the dense layout.
@@ -355,15 +355,17 @@ def insert_cache_slots(
     for g_full, (pattern, _) in zip(full, block_groups(cfg)):
         for j, kind in enumerate(pattern):
             if kind == "attn":
-                block_len = g_full[str(j)]["k"].shape[2]
+                block_len = g_full[str(j)]["k"].shape[3]
     assert block_len is not None, "paged insert on an attn-free arch"
     n_pages = pages.shape[1]
 
     def _scatter_attn(f, p):
-        # p: (layers, Bn, s_c, KV, hd) -> page-cut -> pool scatter
-        lyr, bn = p.shape[:2]
-        pr = p.reshape((lyr, bn, n_pages, block_len) + p.shape[3:])
-        return f.at[:, pages].set(pr.astype(f.dtype))
+        # p: (layers, Bn, KV, s_c, hd) -> page-cut
+        # (layers, Bn, n_pages, KV, block_len, hd) -> pool scatter
+        lyr, bn, kv, _, hd = p.shape
+        pr = p.reshape(lyr, bn, kv, n_pages, block_len, hd)
+        return f.at[:, pages].set(pr.transpose(0, 1, 3, 2, 4, 5)
+                                  .astype(f.dtype))
 
     out = []
     for g_full, g_part, (pattern, _) in zip(full, part, block_groups(cfg)):
@@ -406,7 +408,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype,
     """Cache pytree mirroring the block-group structure (stacked).
 
     With ``paged`` the attn leaves become the shared block pool
-    ``(count, n_blocks, block_len, KV, hd)`` — batch-free; slot -> position
+    ``(count, n_blocks, KV, block_len, hd)`` — batch-free; slot -> position
     resolution happens through the page table at decode/insert time. SSM /
     RG-LRU leaves keep their dense per-slot ``(count, batch, ...)`` shape."""
     if paged is not None and not any(
@@ -447,7 +449,8 @@ def _apply_block_decode(
     if kind == "attn":
         win = _layer_window(cfg)
         mix, cache = attention.decode(p["mix"], cfg, x, cache, pos, window=win,
-                                      pages=pages, write_mask=write_mask)
+                                      pages=pages, write_mask=write_mask,
+                                      mesh=mesh)
     else:
         mix, cache = rglru.decode(p["mix"], cfg, x, cache)
     h = h + mix

@@ -17,8 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 __all__ = ["quantize", "dequantize", "ring_allreduce_int8"]
 
 
@@ -44,7 +42,7 @@ def ring_allreduce_int8(
     Must be called inside shard_map. x: (n,) fp array, n divisible by the
     axis size. Returns the summed result (fp32).
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     n = x.shape[0]
     assert n % p == 0, (n, p)

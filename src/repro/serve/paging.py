@@ -1,7 +1,7 @@
 """Paged KV block pool: host-side allocator + page-table construction.
 
 The device side (models/attention.py, models/transformer.py) stores attn
-KV in a shared ``(n_blocks, block_len, KV, hd)`` pool addressed through
+KV in a shared ``(n_blocks, KV, block_len, hd)`` pool addressed through
 per-slot page tables; this module owns the HOST bookkeeping: which
 physical blocks are free, how many a request needs for its whole
 lifetime, and the ``(n_pages,)`` int32 page-table row the engine commits

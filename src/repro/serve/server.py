@@ -65,7 +65,6 @@ import collections
 import dataclasses
 import logging
 import time
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -260,30 +259,24 @@ class Server:
             state = jax.lax.with_sharding_constraint(state, self._state_sh)
             return cache, state
 
-        # fused decode windows: cache + per-slot state are device-resident
-        # and donated through every dispatch. One jitted variant per window
-        # size the scheduler may pick, compiled lazily on first use.
-        self._pin = _pin
-        self._decode_fns: dict[int, Any] = {}
+        # the decode program: cache + per-slot state are device-resident
+        # and donated through every dispatch. The token count is an
+        # operand, so every window the scheduler picks — and the
+        # reference loop's single token — runs this one compiled program.
+        decode_core = steps_lib.make_decode_loop_step(
+            self.model, scfg.decode_window, scfg.eos_id, scfg.max_seq,
+            strict=scfg.strict, paged=scfg.paged,
+        )
 
-        def _make_decode_fn(window: int):
-            decode_core = steps_lib.make_decode_loop_step(
-                self.model, window, scfg.eos_id, scfg.max_seq,
-                strict=scfg.strict, paged=scfg.paged,
+        def decode_step(params, cache, state, base_key, n, index=None,
+                        router=None):
+            cache, state, toks, oks, emitted, widths = decode_core(
+                params, cache, state, base_key, n, index, router
             )
+            cache, state = _pin(cache, state)
+            return cache, state, toks, oks, emitted, widths
 
-            def decode_step(params, cache, state, base_key, index=None,
-                            router=None):
-                cache, state, toks, oks, emitted, widths = decode_core(
-                    params, cache, state, base_key, index, router
-                )
-                cache, state = _pin(cache, state)
-                return cache, state, toks, oks, emitted, widths
-
-            return jax.jit(decode_step, donate_argnums=(1, 2))
-
-        self._make_decode_fn = _make_decode_fn
-        self.step_fn = self._decode_fn(scfg.decode_window)
+        self.step_fn = jax.jit(decode_step, donate_argnums=(1, 2))
 
         prefill_core = steps_lib.make_prefill_into_cache_step(
             self.model, scfg.max_seq, scfg.eos_id, scfg.max_new_tokens,
@@ -300,12 +293,6 @@ class Server:
             return cache, state, nxt, ok
 
         self.prefill_fn = jax.jit(prefill_step, donate_argnums=(1, 2))
-        # single-step comparator (engine="reference")
-        self.ref_step_fn = jax.jit(
-            steps_lib.make_reference_serve_step(self.model,
-                                                strict=scfg.strict),
-            donate_argnums=(1,),
-        )
         self.cache = self.model.init_cache(scfg.batch_slots, scfg.max_seq,
                                            paged=paged_layout)
         self.key = jax.random.key(scfg.seed)
@@ -352,13 +339,31 @@ class Server:
 
         self._reset_slots = _reset_slots
 
-    def _decode_fn(self, window: int):
-        """The jitted fused-decode variant for ``window`` tokens/dispatch
-        (compiled lazily — the fifo scheduler only ever touches one)."""
-        fn = self._decode_fns.get(window)
-        if fn is None:
-            fn = self._decode_fns[window] = self._make_decode_fn(window)
-        return fn
+    def _fresh_state(self) -> dict:
+        """The engine's per-slot device state with every slot idle."""
+        nslots = self.scfg.batch_slots
+        state = {
+            "ids": jnp.zeros((nslots,), jnp.int32),
+            "pos": jnp.zeros((nslots,), jnp.int32),
+            "active": jnp.zeros((nslots,), bool),
+            "budget": jnp.zeros((nslots,), jnp.int32),
+            "rid": jnp.full((nslots,), -1, jnp.int32),
+        }
+        if self.spec is not None:
+            state["pages"] = jnp.full((nslots, self.spec.n_pages),
+                                      self.spec.sentinel, jnp.int32)
+        return state
+
+    def decode_program_text(self) -> str:
+        """Optimized HLO of this server's decode program (both engines run
+        it), compiled from shapes."""
+        state = jax.eval_shape(self._fresh_state)
+        n = jax.ShapeDtypeStruct((), jnp.int32)
+        lowered = self.step_fn.lower(
+            self.params, self.cache, state, self.key, n, self.index,
+            self.router,
+        )
+        return lowered.compile().as_text()
 
     def _index_health(self, where: str) -> None:
         """Surface index health where an operator looks: ``stats`` carries
@@ -578,16 +583,7 @@ class Server:
         due = collections.deque(due)  # arrival-sorted (t_enq, rid)
         waiting: list[int] = []  # arrived, not yet admitted
 
-        state = {
-            "ids": jnp.zeros((nslots,), jnp.int32),
-            "pos": jnp.zeros((nslots,), jnp.int32),
-            "active": jnp.zeros((nslots,), bool),
-            "budget": jnp.zeros((nslots,), jnp.int32),
-            "rid": jnp.full((nslots,), -1, jnp.int32),
-        }
-        if self.spec is not None:
-            state["pages"] = jnp.full((nslots, self.spec.n_pages),
-                                      self.spec.sentinel, jnp.int32)
+        state = self._fresh_state()
         cache = self.cache
         if self._cache_sh is not None:  # one jit signature across runs
             state = jax.device_put(state, self._state_sh)
@@ -638,7 +634,7 @@ class Server:
                                 else 0.7 * self._itl_ms + 0.3 * dt_ms)
                 self._bin_widths(widths, emitted)
                 now = time.perf_counter()
-                for t in range(toks.shape[0]):
+                for t in range(window):
                     for slot in range(nslots):
                         if not emitted[t, slot]:
                             continue
@@ -732,10 +728,10 @@ class Server:
                     waiting, reqs, now, self._itl_ms, self._windows
                 )
                 t_issue = time.perf_counter()
-                cache, state, toks, oks, emitted, widths = self._decode_fn(
-                    window
-                )(self.params, cache, state, base_key, self.index,
-                  self.router)
+                cache, state, toks, oks, emitted, widths = self.step_fn(
+                    self.params, cache, state, base_key, np.int32(window),
+                    self.index, self.router,
+                )
                 pending.append(("decode", (toks, oks, emitted, widths),
                                 list(slot_req), window, t_issue))
                 self.stats["decode_dispatches"] += 1
@@ -762,9 +758,11 @@ class Server:
     # -------------------------------------------------- reference single-step
     def _run_reference(self, prompts: list[list[int]]) -> list[RequestResult]:
         """Teacher-forced single-step loop: one dispatch per token, prompts
-        fed through the decode path. Kept as the engine's correctness
-        comparator (same key discipline ⇒ identical samples) and as the
-        benchmark baseline for the fused/pipelined speedup."""
+        fed through the decode path — the engine's decode program with a
+        window of one token, its slot state set by the host each step.
+        Kept as the engine's correctness comparator (same key discipline ⇒
+        identical samples) and as the benchmark baseline for the
+        fused/pipelined speedup."""
         s = self.scfg
         nslots = s.batch_slots
         results: list[RequestResult] = []
@@ -778,6 +776,8 @@ class Server:
         pos_h = np.zeros((nslots,), np.int32)
         rids_h = np.full((nslots,), -1, np.int32)
         cache = self.cache
+        if self._cache_sh is not None:  # the engine's jit signature
+            cache = jax.device_put(cache, self._cache_sh)
 
         def admit(slot) -> None:
             if not queue:
@@ -805,22 +805,25 @@ class Server:
                     ids_h[i] = req["prompt"][req["fed"]]
                 else:
                     ids_h[i] = req["out"][-1]
-            nxt, ok, cache, pos, width = self.ref_step_fn(
-                self.params, cache, jnp.asarray(ids_h), jnp.asarray(pos_h),
-                jnp.asarray(rids_h), base_key, self.index, self.router,
+            live = np.asarray([a is not None for a in active])
+            state = {
+                "ids": jnp.asarray(ids_h), "pos": jnp.asarray(pos_h),
+                "active": jnp.asarray(live),
+                "budget": jnp.full((nslots,), s.max_seq, jnp.int32),
+                "rid": jnp.asarray(rids_h),
+            }
+            cache, _, toks, oks, _, widths = self.step_fn(
+                self.params, cache, state, base_key, np.int32(1), self.index,
+                self.router,
             )
-            nxt_h = np.asarray(nxt)
-            ok_h = np.asarray(ok)
-            pos_h = np.array(pos)  # device value is authoritative
-            self._bin_widths(
-                np.asarray(width),
-                np.asarray([a is not None for a in active]),
-            )
+            nxt_h = np.asarray(toks[0])
+            ok_h = np.asarray(oks[0])
+            self._bin_widths(np.asarray(widths[:1]), live[None])
             self.stats["steps"] += 1
             now = time.perf_counter()
+            pos_h = pos_h + live  # idle slots stay frozen, as in the engine
             for i, rid in enumerate(active):
                 if rid is None:
-                    pos_h[i] -= 1  # idle slot: freeze (mirror the engine)
                     continue
                 req = reqs[rid]
                 if req["fed"] < len(req["prompt"]):

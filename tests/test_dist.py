@@ -15,6 +15,7 @@ def _run(body: str, devices: int = 8, timeout: int = 540) -> str:
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import warnings; warnings.filterwarnings("ignore")
+        from jax.sharding import AxisType
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
@@ -34,7 +35,7 @@ def test_dist_head_loss_matches_single_device():
         from repro.core.amortized_head import HeadConfig, head_loss, make_index
         from repro.models.head import dist_head_loss
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         N, D, T = 4096, 32, 16
         emb = jax.random.normal(jax.random.key(0), (N, D)) / np.sqrt(D)
         h = jax.random.normal(jax.random.key(1), (T, D)) * 2.0
@@ -91,7 +92,7 @@ def test_dist_head_sample_distribution():
         from repro.core.amortized_head import HeadConfig, make_index
         from repro.models.head import dist_head_sample
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         N, D = 2048, 16
         emb = jax.random.normal(jax.random.key(0), (N, D)) / np.sqrt(D)
         h = jnp.broadcast_to(
@@ -144,7 +145,7 @@ def test_dist_trainstep_runs_and_loss_decreases():
         from repro.optim import adamw
         from repro.data.synthetic import DataConfig, make_batch
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke("tinyllama-1.1b").scaled(
             d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, vocab=4096,
             head_mode="amortized")
@@ -194,7 +195,7 @@ def test_sharded_index_refresh_without_recompile():
         from repro.serve.server import ServeConfig, Server
         from repro.train.trainer import RunConfig, Trainer
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke("tinyllama-1.1b").scaled(
             d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, vocab=4096,
             head_mode="amortized", head_mips="ivf", head_k=128, head_l=128)
@@ -280,7 +281,7 @@ def test_sharded_ivfpq_build_refresh_query():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import mips
 
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
         n, d = 4096, 32
         k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
         centers = jax.random.normal(k1, (64, d))
@@ -338,7 +339,7 @@ def test_dist_fused_decode_bitwise_parity():
         from repro.core.amortized_head import HeadConfig, make_index
         from repro.models.head import dist_head_sample
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         N, D, T = 4096, 32, 8
         emb = jax.random.normal(jax.random.key(0), (N, D))
         emb = emb / jnp.linalg.norm(emb, axis=1, keepdims=True)
@@ -377,7 +378,7 @@ def test_dist_adaptive_probe_parity_and_staging():
         from repro.core.mips.adaptive import stage_widths
         from repro.models.head import dist_head_sample
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         N, D, T = 4096, 32, 8
         emb = jax.random.normal(jax.random.key(0), (N, D))
         emb = emb / jnp.linalg.norm(emb, axis=1, keepdims=True)
@@ -420,14 +421,51 @@ def test_dist_adaptive_probe_parity_and_staging():
     assert "OK" in out
 
 
+def test_flash_decode_kernel_on_mesh():
+    """A Pallas kernel cannot be partitioned by the compiler: on a mesh the
+    decode and prefill attention kernels run once per shard (KV heads over
+    "model", batch over "data") and match the unsharded kernels."""
+    out = _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs import get_smoke
+        from repro.models import attention
+
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        cfg = get_smoke("tinyllama-1.1b").scaled(n_heads=8, n_kv_heads=4)
+        p = attention.init(jax.random.key(0), cfg)
+        b, s = 4, 16
+        ks = jax.random.split(jax.random.key(1), 4)
+        x = jax.random.normal(ks[0], (b, 1, cfg.d_model), jnp.float32)
+        cache = {
+            n: jax.random.normal(kk, (b, cfg.n_kv_heads, s, cfg.head_dim))
+            for n, kk in zip("kv", ks[1:3])
+        }
+        pos = jnp.array([0, 5, 15, 20], jnp.int32)
+        dec = lambda m: jax.jit(lambda x, c, pp: attention.decode(
+            p, cfg, x, c, pp, use_kernel=True, mesh=m))(x, cache, pos)
+        (o1, c1), (o2, c2) = dec(None), dec(mesh)
+        np.testing.assert_allclose(np.asarray(o2), np.asarray(o1),
+                                   rtol=1e-5, atol=1e-5)
+        xs = jax.random.normal(ks[3], (b, 6, cfg.d_model), jnp.float32)
+        ps = jnp.broadcast_to(jnp.arange(6), (b, 6))
+        pre = lambda m: jax.jit(lambda x: attention.prefill(
+            p, cfg, x, ps, s, use_kernel=True, mesh=m))(xs)
+        (o1, c1), (o2, c2) = pre(None), pre(mesh)
+        np.testing.assert_allclose(np.asarray(o2), np.asarray(o1),
+                                   rtol=1e-5, atol=1e-5)
+        print("OK")
+    """)
+    assert "OK" in out
+
+
 def test_compressed_allreduce_matches_psum():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.optim.compress import ring_allreduce_int8
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         x = jax.random.normal(jax.random.key(0), (8, 4096))
 
         def local(xl, key):
@@ -436,7 +474,7 @@ def test_compressed_allreduce_matches_psum():
             exact = jax.lax.psum(flat, "data")
             return approx, exact
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=(P("data"), P()),
             out_specs=(P(None), P(None)), check_vma=False))
         approx, exact = f(x, jax.random.key(1))
@@ -466,7 +504,7 @@ def test_dryrun_entry_on_tiny_mesh():
         from repro.launch import mesh as meshlib, steps
         from repro.launch.dryrun import lower_cell
         from repro.launch.hlo_analysis import analyze_hlo
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         lowered, n_tokens, kind = lower_cell(
             "stablelm-3b", "train_4k", mesh, steps.TrainConfig(accum=4))
         comp = lowered.compile()

@@ -37,14 +37,16 @@ _CHILD = """
     )
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
     from repro.checkpoint import manager as ckpt
     from repro.core import mips
 
     assert jax.process_count() == 2 and len(jax.devices()) == 4
 
     # DP x TP mesh: "data" spans the two processes, "model" is host-local
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
     def place(arr, spec):
         # multi-process arrays WITHOUT collectives: slice per local device

@@ -112,3 +112,20 @@ def test_padded_vocab_rows_never_contribute(setup):
     np.testing.assert_allclose(
         np.asarray(lp.loss), np.asarray(le.loss), rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_sampler_query_rounding_is_an_op(setup, fused):
+    """A bf16 query reaches every scoring stage through an explicit
+    ``reduce_precision`` to bf16: XLA may otherwise hand a Pallas kernel
+    the unrounded f32 it came from, and the fused and unfused samplers
+    then score different queries."""
+    emb, h, _ = setup
+    cfg = HeadConfig(n=N, mode="amortized", mips="ivf", min_amortized_n=1,
+                     use_kernel=True, fused_decode=fused)
+    index = make_index(cfg, emb)
+    keys = jax.random.split(jax.random.key(3), T)
+    text = str(jax.make_jaxpr(
+        lambda hh: head_sample(emb, hh, None, cfg, index=index, keys=keys)
+    )(h.astype(jnp.bfloat16)))
+    assert "reduce_precision[exponent_bits=8 mantissa_bits=7]" in text

@@ -2,9 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.roofline import collective_bytes
+from repro.launch.roofline import collective_bytes, peaks
 
 
 def _compile(f, *args):
@@ -84,3 +85,12 @@ def test_collective_bytes_regex_fallback():
     assert got["counts"] == {"all-gather": 1, "all-reduce": 1}
     # ag operand = result/2 = 64KB; ar operand = 128B
     assert abs(got["total"] - (256 * 128 * 4 / 2 + 64 * 2)) < 1
+
+
+def test_peaks_keyed_by_device_kind():
+    """One table of published peaks; a kind it does not hold is an error,
+    never a default."""
+    v5e = peaks("TPU v5 lite")
+    assert v5e["peak_flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")

@@ -75,8 +75,8 @@ def test_fused_estimator_all_masked_but_one():
 def test_flash_decode_sweep(b, hq, hkv, s, hd, s_block, dtype):
     k1, k2, k3, k4 = jax.random.split(jax.random.key(4), 4)
     q = jax.random.normal(k1, (b, hq, hd), dtype=dtype)
-    kc = jax.random.normal(k2, (b, s, hkv, hd), dtype=dtype)
-    vc = jax.random.normal(k3, (b, s, hkv, hd), dtype=dtype)
+    kc = jax.random.normal(k2, (b, hkv, s, hd), dtype=dtype)
+    vc = jax.random.normal(k3, (b, hkv, s, hd), dtype=dtype)
     lens = jax.random.randint(k4, (b,), 1, s + 1)
     out = flash_decode(q, kc, vc, lens, s_block=s_block, interpret=True)
     want = ref.flash_decode_ref(q, kc, vc, lens)
@@ -88,8 +88,8 @@ def test_flash_decode_length_one():
     """Cache with a single valid entry -> output = v[0] exactly."""
     b, hq, hkv, s, hd = 1, 2, 1, 256, 32
     q = jax.random.normal(jax.random.key(5), (b, hq, hd))
-    kc = jax.random.normal(jax.random.key(6), (b, s, hkv, hd))
-    vc = jax.random.normal(jax.random.key(7), (b, s, hkv, hd))
+    kc = jax.random.normal(jax.random.key(6), (b, hkv, s, hd))
+    vc = jax.random.normal(jax.random.key(7), (b, hkv, s, hd))
     lens = jnp.array([1], jnp.int32)
     out = flash_decode(q, kc, vc, lens, s_block=64, interpret=True)
     np.testing.assert_allclose(
@@ -283,8 +283,8 @@ def test_opaque_stubs_match_real_shapes():
          (S((128, 64), f32), S((3, 24), i32), S((3, 64), f32),
           S((3, 24), f32)), {}),
         (ops.flash_decode,
-         (S((2, 4, 32), f32), S((2, 512, 2, 32), f32),
-          S((2, 512, 2, 32), f32), S((2,), i32)), {}),
+         (S((2, 4, 32), f32), S((2, 2, 512, 32), f32),
+          S((2, 2, 512, 32), f32), S((2,), i32)), {}),
         (ops.ivf_screen_select,
          (S((8, 16, 64), f32), S((8, 16), i32), S((3, 8), f32),
           S((8,), i32), S((3, 4), i32), S((3, 64), f32)), {"k": 8}),

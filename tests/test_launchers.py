@@ -2,6 +2,7 @@
 (notably ``--mips lsh``, exposed by launch/train.py and launch/serve.py)."""
 import json
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -11,6 +12,34 @@ import repro.models.transformer as T
 @pytest.fixture(autouse=True)
 def _no_remat(monkeypatch):
     monkeypatch.setattr(T, "REMAT", False)
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache(monkeypatch):
+    """main() turns JAX's persistent compilation cache on for the process;
+    in-process launcher tests keep it off for the rest of the worker."""
+    from repro.launch import serve, train
+
+    for mod in (serve, train):
+        monkeypatch.setattr(mod, "enable_compile_cache", lambda: "")
+
+
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; else a fixed path at the
+    checkout root."""
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cc.enable_compile_cache() == "/elsewhere"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = cc.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert Path(path) == Path(cc.__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _json_tail(out: str) -> dict:

@@ -159,3 +159,39 @@ def test_skip_matrix_documented():
         assert (a, "long_500k") in skips
     for a in ["mixtral-8x22b", "mamba2-780m", "recurrentgemma-9b"]:
         assert not skip_reason(get(a), "long_500k")
+
+
+def test_prefill_kernel_matches_decode_kernel():
+    """Where decode runs the flash_decode kernel, a causal prompt attends
+    through flash_prefill: each position, over its own prefix of the ring
+    prefill builds, gets what the decode kernel computes for the token at
+    that position, bit for bit — so a batched prefill and a token-by-token
+    decode agree — and the result is blockwise attention's."""
+    from repro.models import attention
+
+    cfg = get_smoke("tinyllama-1.1b")
+    p = attention.init(jax.random.key(0), cfg)
+    b, l, max_seq = 3, 12, 16
+    x = jax.random.normal(jax.random.key(1), (b, l, cfg.d_model), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(l), (b, l))
+    lengths = jnp.array([12, 5, 1], jnp.int32)
+    out_ref, cache_ref = attention.prefill(p, cfg, x, pos, max_seq,
+                                           lengths=lengths, use_kernel=False)
+    out_k, cache_k = attention.prefill(p, cfg, x, pos, max_seq,
+                                       lengths=lengths, use_kernel=True)
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(cache_k[key], cache_ref[key])
+    valid = np.arange(l)[None, :] < np.asarray(lengths)[:, None]
+    np.testing.assert_allclose(
+        np.asarray(out_k)[valid], np.asarray(out_ref)[valid],
+        rtol=1e-5, atol=1e-5,
+    )
+    # token by token through the decode kernel
+    cache = attention.init_cache(cfg, b, max_seq, jnp.float32)
+    for t in range(l):
+        o, cache = attention.decode(p, cfg, x[:, t : t + 1], cache,
+                                    jnp.full((b,), t, jnp.int32),
+                                    use_kernel=True)
+        np.testing.assert_array_equal(np.asarray(o[:, 0])[valid[:, t]],
+                                      np.asarray(out_k[:, t])[valid[:, t]])
+
