@@ -66,8 +66,6 @@ def _serve(cfg, params, prompts, *, engine, window, slots, new_tokens,
         "tokens_per_s": round(toks / st["wall_s"], 1),
         "prefill_tokens": st["prefill_tokens"],
         "dispatches": st["steps"],
-        "prefill_s": round(st["prefill_s"], 4),
-        "decode_s": round(st["decode_s"], 4),
         "ttft_p50_ms": round(1e3 * float(np.median(
             [r.ttft_s for r in results])), 2),
         "ok_rate": round(st["ok"] / max(st["tokens"], 1), 4),

@@ -49,6 +49,7 @@ def sample_complement(
     (callers must weight such draws out).
     """
     k = s_sorted.shape[0] if n_excluded is None else n_excluded
-    hi = jnp.maximum(jnp.asarray(n, jnp.int32) - k, 1)
-    u = jax.random.randint(key, (num,), 0, hi, dtype=jnp.int32)
-    return complement_map(u, s_sorted.astype(jnp.int32))
+    with jax.named_scope("tail.draw"):
+        hi = jnp.maximum(jnp.asarray(n, jnp.int32) - k, 1)
+        u = jax.random.randint(key, (num,), 0, hi, dtype=jnp.int32)
+        return complement_map(u, s_sorted.astype(jnp.int32))

@@ -125,14 +125,16 @@ def plan_tail(
     The exact sequence of jax.random draws of the pre-refactor ``_finish``,
     so samples are reproducible across the fused/unfused split."""
     k_m, k_pos, k_h = jax.random.split(key, 3)
-    m = jax.random.poisson(k_m, lam, dtype=jnp.int32)
-    overflow = m > m_cap
-    m_used = jnp.minimum(m, m_cap)
-    s_sorted = jnp.sort(topk_ids).astype(jnp.int32)
+    with jax.named_scope("tail.draw"):
+        m = jax.random.poisson(k_m, lam, dtype=jnp.int32)
+        overflow = m > m_cap
+        m_used = jnp.minimum(m, m_cap)
+        s_sorted = jnp.sort(topk_ids).astype(jnp.int32)
+        heights = b + jax.random.exponential(k_h, (m_cap,),
+                                             dtype=jnp.float32)
     pos = sample_complement(
         k_pos, n, s_sorted, m_cap, n_excluded=k_valid
     )  # (m_cap,)
-    heights = b + jax.random.exponential(k_h, (m_cap,), dtype=jnp.float32)
     return TailPlan(pos, heights, m_used, overflow)
 
 
@@ -187,7 +189,8 @@ def _finish(
 ) -> SampleResult:
     """Shared tail materialization + argmax given cutoff b and atom rate lam."""
     plan = plan_tail(key, topk.ids, n, b, lam, m_cap, k_valid=k_valid)
-    y_tail = score_fn(plan.pos).astype(jnp.float32)  # (m_cap,)
+    with jax.named_scope("tail.score"):
+        y_tail = score_fn(plan.pos).astype(jnp.float32)  # (m_cap,)
     live = jnp.arange(m_cap, dtype=jnp.int32) < plan.m_used
     pert_t = jnp.where(live, y_tail + plan.heights, -jnp.inf)
 

@@ -339,29 +339,33 @@ class IVFIndex:
         liveness mask so the fixed and adaptive paths share this exactly)."""
         state = self.state
         b = qf.shape[0]
-        if self.config.use_kernel:
-            from repro.kernels import ops as kops
+        with jax.named_scope("ivf.members"):
+            if self.config.use_kernel:
+                from repro.kernels import ops as kops
 
-            scores, ids = kops.ivf_gather_score(
-                state.member_vecs, state.member_ids, probe, qf
-            )  # (b, n_probe*cap)
-        else:
-            vecs = state.member_vecs[probe]  # (b, n_probe, cap, d)
-            ids = state.member_ids[probe].reshape(b, -1)  # (b, n_probe*cap)
-            scores = jnp.einsum("bpcd,bd->bpc", vecs.astype(jnp.float32), qf)
-            scores = scores.reshape(b, -1)
+                scores, ids = kops.ivf_gather_score(
+                    state.member_vecs, state.member_ids, probe, qf
+                )  # (b, n_probe*cap)
+            else:
+                ids = state.member_ids[probe].reshape(b, -1)
+                vecs = state.member_vecs[probe]  # (b, n_probe, cap, d)
+                scores = jnp.einsum("bpcd,bd->bpc",
+                                    vecs.astype(jnp.float32), qf)
+                scores = scores.reshape(b, -1)  # (b, n_probe*cap)
 
-        o_scores = state.overflow_vecs.astype(jnp.float32) @ qf.T  # (o_cap, b)
-        scores = jnp.concatenate([scores, o_scores.T], axis=1)
-        ids = jnp.concatenate(
-            [
-                ids,
-                jnp.broadcast_to(
-                    state.overflow_ids, (b,) + state.overflow_ids.shape
-                ),
-            ],
-            axis=1,
-        )
+        with jax.named_scope("ivf.overflow"):
+            o_scores = (state.overflow_vecs.astype(jnp.float32) @ qf.T).T
+        with jax.named_scope("ivf.select"):
+            scores = jnp.concatenate([scores, o_scores], axis=1)
+            ids = jnp.concatenate(
+                [
+                    ids,
+                    jnp.broadcast_to(
+                        state.overflow_ids, (b,) + state.overflow_ids.shape
+                    ),
+                ],
+                axis=1,
+            )
         return scores, ids
 
     def topk_batch(
@@ -370,14 +374,16 @@ class IVFIndex:
         """Approximate top-k for a query batch (b, d) -> TopK[(b,k), (b,k)]."""
         state = self.state
         n_probe = min(n_probe or self.config.n_probe, state.n_clusters)
-        qf = q.astype(jnp.float32)
-        c_scores = qf @ state.centroids.T  # (b, n_c)
-        _, probe = jax.lax.top_k(c_scores, n_probe)  # (b, n_probe)
+        with jax.named_scope("ivf.centroids"):
+            qf = q.astype(jnp.float32)
+            c_scores = qf @ state.centroids.T  # (b, n_c)
+            _, probe = jax.lax.top_k(c_scores, n_probe)  # (b, n_probe)
         scores, ids = self._pool_scores(qf, probe)
-        scores = jnp.where(ids >= 0, scores, -jnp.inf)
-        scores, ids = _pad_pool(scores, ids, k)
-        vals, pos = jax.lax.top_k(scores, k)
-        return TopK(jnp.take_along_axis(ids, pos, axis=1), vals)
+        with jax.named_scope("ivf.select"):
+            scores = jnp.where(ids >= 0, scores, -jnp.inf)
+            scores, ids = _pad_pool(scores, ids, k)
+            vals, pos = jax.lax.top_k(scores, k)
+            return TopK(jnp.take_along_axis(ids, pos, axis=1), vals)
 
     def topk_adaptive(
         self,

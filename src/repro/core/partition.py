@@ -46,13 +46,15 @@ def partition_estimate(
 ) -> PartitionEstimate:
     """Algorithm 3. ``score_fn`` maps ids -> unnormalized log-probs."""
     k = topk.ids.shape[0]
-    s_sorted = jnp.sort(topk.ids).astype(jnp.int32)
+    with jax.named_scope("tail.draw"):
+        s_sorted = jnp.sort(topk.ids).astype(jnp.int32)
     tail_ids = sample_complement(key, n, s_sorted, l)
     # y over S is RECOMPUTED through score_fn (not read from topk.values):
     # keeps Ẑ differentiable w.r.t. the parameters through both strata
     # (∇ log Ẑ = Algorithm 4 with f = φ) and robust to stale index values.
-    y_s = score_fn(topk.ids.astype(jnp.int32)).astype(jnp.float32)
-    y_t = score_fn(tail_ids).astype(jnp.float32)
+    with jax.named_scope("tail.score"):
+        y_s = score_fn(topk.ids.astype(jnp.int32)).astype(jnp.float32)
+        y_t = score_fn(tail_ids).astype(jnp.float32)
     log_w_tail = jnp.log((jnp.asarray(n, jnp.float32) - k) / l)
     log_z = stratified_logsumexp(y_s, y_t, log_w_tail)
     return PartitionEstimate(log_z, tail_ids, y_t)

@@ -234,27 +234,29 @@ class Model:
                                                   mesh=self.mesh, pages=pages,
                                                   write_mask=write_mask)
         hq = h[:, 0]  # (B, d)
-        if self._head_mesh() is not None:
-            if strict:
-                raise NotImplementedError(
-                    "strict exact-fallback is not wired through the "
-                    "distributed head; serve with strict=False on a TP mesh"
+        with jax.named_scope("head"):
+            if self._head_mesh() is not None:
+                if strict:
+                    raise NotImplementedError(
+                        "strict exact-fallback is not wired through the "
+                        "distributed head; serve with strict=False on a TP "
+                        "mesh"
+                    )
+                nxt, ok, width = dist_head.dist_head_sample(
+                    self.mesh, self._out_embed(params), hq, key,
+                    self.head_cfg, index=index, keys=keys, router=router,
                 )
-            nxt, ok, width = dist_head.dist_head_sample(
-                self.mesh, self._out_embed(params), hq, key, self.head_cfg,
-                index=index, keys=keys, router=router,
-            )
-        else:
-            res = ah.head_sample(
-                self._out_embed(params), hq, key, self.head_cfg, index=index,
-                keys=keys, strict=strict, strict_live=strict_live,
-                router=router,
-            )
-            nxt, ok = res.index, res.ok
-            width = (
-                res.width.astype(jnp.int32) if res.width is not None
-                else jnp.full(nxt.shape, -1, jnp.int32)
-            )
+            else:
+                res = ah.head_sample(
+                    self._out_embed(params), hq, key, self.head_cfg,
+                    index=index, keys=keys, strict=strict,
+                    strict_live=strict_live, router=router,
+                )
+                nxt, ok = res.index, res.ok
+                width = (
+                    res.width.astype(jnp.int32) if res.width is not None
+                    else jnp.full(nxt.shape, -1, jnp.int32)
+                )
         return nxt, ok, cache, width
 
     def prefill(
@@ -272,16 +274,18 @@ class Model:
             mesh=self.mesh,
         )
         hq = h[:, -1]
-        if self._head_mesh() is not None:
-            nxt, ok, _ = dist_head.dist_head_sample(
-                self.mesh, self._out_embed(params), hq, key, self.head_cfg,
-                index=index,
-            )
-        else:
-            res = ah.head_sample(
-                self._out_embed(params), hq, key, self.head_cfg, index=index
-            )
-            nxt, ok = res.index, res.ok
+        with jax.named_scope("head"):
+            if self._head_mesh() is not None:
+                nxt, ok, _ = dist_head.dist_head_sample(
+                    self.mesh, self._out_embed(params), hq, key,
+                    self.head_cfg, index=index,
+                )
+            else:
+                res = ah.head_sample(
+                    self._out_embed(params), hq, key, self.head_cfg,
+                    index=index,
+                )
+                nxt, ok = res.index, res.ok
         return nxt, ok, jnp.full((b,), l, jnp.int32), cache
 
     def prefill_into_cache(
@@ -329,23 +333,25 @@ class Model:
         hq = jnp.take_along_axis(
             h, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
         )[:, 0]  # (Bn, d): hidden state at each row's last valid token
-        if self._head_mesh() is not None:
-            if strict:
-                raise NotImplementedError(
-                    "strict exact-fallback is not wired through the "
-                    "distributed head; serve with strict=False on a TP mesh"
+        with jax.named_scope("head"):
+            if self._head_mesh() is not None:
+                if strict:
+                    raise NotImplementedError(
+                        "strict exact-fallback is not wired through the "
+                        "distributed head; serve with strict=False on a TP "
+                        "mesh"
+                    )
+                nxt, ok, _ = dist_head.dist_head_sample(
+                    self.mesh, self._out_embed(params), hq, None,
+                    self.head_cfg, index=index, keys=keys,
                 )
-            nxt, ok, _ = dist_head.dist_head_sample(
-                self.mesh, self._out_embed(params), hq, None, self.head_cfg,
-                index=index, keys=keys,
-            )
-        else:
-            res = ah.head_sample(
-                self._out_embed(params), hq, None, self.head_cfg,
-                index=index, keys=keys, strict=strict,
-                strict_live=strict_live,
-            )
-            nxt, ok = res.index, res.ok
+            else:
+                res = ah.head_sample(
+                    self._out_embed(params), hq, None, self.head_cfg,
+                    index=index, keys=keys, strict=strict,
+                    strict_live=strict_live,
+                )
+                nxt, ok = res.index, res.ok
         cache = transformer.insert_cache_slots(cache, part, slots, cfg=cfg,
                                                pages=pages)
         return nxt, ok, cache

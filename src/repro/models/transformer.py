@@ -267,24 +267,27 @@ def _apply_block_prefill(
         return h + mix, cache
     if kind == "attn":
         win = _layer_window(cfg)
-        mix, cache = attention.prefill(
-            p["mix"], cfg, x, positions, max_seq, window=win, prefix=prefix,
-            lengths=lengths, mesh=mesh,
-        )
+        with jax.named_scope("attention"):
+            mix, cache = attention.prefill(
+                p["mix"], cfg, x, positions, max_seq, window=win,
+                prefix=prefix, lengths=lengths, mesh=mesh,
+            )
     else:
         mix, cache = rglru.forward(p["mix"], cfg, x, return_cache=True,
                                    lengths=lengths)
     h = h + mix
     x = rms_norm(h, p["norm2"], cfg.norm_eps)
-    if cfg.is_moe:
-        b, l, d = x.shape
-        if mesh is not None and "model" in mesh.shape:
-            out, _ = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d), mesh)
+    with jax.named_scope("mlp"):
+        if cfg.is_moe:
+            b, l, d = x.shape
+            if mesh is not None and "model" in mesh.shape:
+                out, _ = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d),
+                                          mesh)
+            else:
+                out, _ = moe.forward(p["mlp"], cfg, x.reshape(-1, d))
+            out = out.reshape(b, l, d)
         else:
-            out, _ = moe.forward(p["mlp"], cfg, x.reshape(-1, d))
-        out = out.reshape(b, l, d)
-    else:
-        out = swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
+            out = swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
     return h + out, cache
 
 
@@ -448,22 +451,25 @@ def _apply_block_decode(
         return h + mix, cache
     if kind == "attn":
         win = _layer_window(cfg)
-        mix, cache = attention.decode(p["mix"], cfg, x, cache, pos, window=win,
-                                      pages=pages, write_mask=write_mask,
-                                      mesh=mesh)
+        with jax.named_scope("attention"):
+            mix, cache = attention.decode(p["mix"], cfg, x, cache, pos,
+                                          window=win, pages=pages,
+                                          write_mask=write_mask, mesh=mesh)
     else:
         mix, cache = rglru.decode(p["mix"], cfg, x, cache)
     h = h + mix
     x = rms_norm(h, p["norm2"], cfg.norm_eps)
-    if cfg.is_moe:
-        b, _, d = x.shape
-        if mesh is not None and "model" in mesh.shape:
-            out, _ = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d), mesh)
+    with jax.named_scope("mlp"):
+        if cfg.is_moe:
+            b, _, d = x.shape
+            if mesh is not None and "model" in mesh.shape:
+                out, _ = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d),
+                                          mesh)
+            else:
+                out, _ = moe.forward(p["mlp"], cfg, x.reshape(-1, d))
+            out = out.reshape(b, 1, d)
         else:
-            out, _ = moe.forward(p["mlp"], cfg, x.reshape(-1, d))
-        out = out.reshape(b, 1, d)
-    else:
-        out = swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
+            out = swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
     return h + out, cache
 
 

@@ -79,6 +79,9 @@ from repro.serve import paging, scheduler as sched_lib
 __all__ = ["ServeConfig", "Server", "RequestResult"]
 
 _LOG = logging.getLogger("repro.serve")
+# host spans of the engine loop, on the profiler's clock (the device
+# trace's): one per loop phase, none per token
+_span = jax.profiler.TraceAnnotation
 
 
 def _warn(msg: str) -> None:
@@ -300,7 +303,9 @@ class Server:
             "steps": 0, "tokens": 0, "ok": 0, "fallbacks": 0,
             "prefill_dispatches": 0, "decode_dispatches": 0,
             "prefill_tokens": 0, "rejected": 0,
-            "prefill_s": 0.0, "decode_s": 0.0,
+            # positions the prefill programs computed: batch_slots x the
+            # prompt bucket per dispatch (prefill_tokens of them are real)
+            "prefill_positions": 0,
             # adaptive probe: emitted-token counts per effective probe
             # width {width: count} — empty on fixed-width serving
             "probe_width_hist": {},
@@ -603,54 +608,107 @@ class Server:
             slot_req[slot] = None
             free.append(slot)
 
+        def admit(now):
+            """Scheduler order, block allocation and the host batch: (the
+            admitted rids, their slots, host arrays (tokens, lengths,
+            slots, rids, pages or None)), or ([], [], None)."""
+            free.sort()
+            batch: list[int] = []
+            slots_h: list[int] = []
+            rows: list[np.ndarray] = []
+            for rid in self.sched.order(waiting, reqs, now):
+                if not free:
+                    break
+                req = reqs[rid]
+                if self.alloc is not None:
+                    if not self.alloc.can_alloc(req["pages_needed"]):
+                        self.stats["block_stalls"] += 1
+                        if self.sched.skip_blocked:
+                            continue  # smaller requests may still fit
+                        break  # fifo: strict head-of-line order
+                    req["blocks"] = self.alloc.alloc(req["pages_needed"])
+                    rows.append(paging.page_row(self.spec, req["blocks"]))
+                batch.append(rid)
+                slots_h.append(free.pop(0))
+            if not batch:
+                return batch, slots_h, None
+            t_admit = time.perf_counter()
+            for rid, slot in zip(batch, slots_h):
+                waiting.remove(rid)
+                slot_req[slot] = rid
+                reqs[rid]["t_admit"] = t_admit
+            lp = _bucket(max(len(reqs[r]["prompt"]) for r in batch),
+                         s.prefill_chunk)
+            tokens = np.zeros((nslots, lp), np.int32)
+            lengths = np.ones((nslots,), np.int32)
+            slots = np.full((nslots,), nslots, np.int32)  # pad rows
+            rids = np.full((nslots,), -1, np.int32)
+            for row, (rid, slot) in enumerate(zip(batch, slots_h)):
+                p = reqs[rid]["prompt"]
+                tokens[row, : len(p)] = p
+                lengths[row] = len(p)
+                slots[row] = slot
+                rids[row] = rid
+            pages = None
+            if self.spec is not None:
+                pages = np.full((nslots, self.spec.n_pages),
+                                self.spec.sentinel, np.int32)
+                for row, pr in enumerate(rows):
+                    pages[row] = pr
+            return batch, slots_h, (tokens, lengths, slots, rids, pages)
+
         def process(entry) -> None:
             kind = entry[0]
-            t0 = time.perf_counter()
-            if kind == "prefill":
-                _, arrs, batch, slots_h = entry
-                nxt, ok = (np.asarray(a) for a in arrs)
-                self.stats["prefill_s"] += time.perf_counter() - t0
-                now = time.perf_counter()
-                for row, (rid, slot) in enumerate(zip(batch, slots_h)):
+            with _span("serve.sync"):
+                arrs = [np.asarray(a) for a in entry[1]]
+            with _span("serve.emit"):
+                if kind == "prefill":
+                    emit_prefill(arrs, *entry[2:])
+                else:
+                    emit_decode(arrs, *entry[2:])
+
+        def emit_prefill(arrs, batch, slots_h) -> None:
+            nxt, ok = arrs
+            now = time.perf_counter()
+            for row, (rid, slot) in enumerate(zip(batch, slots_h)):
+                req = reqs[rid]
+                req["out"].append(int(nxt[row]))
+                req["ok"] += bool(ok[row])
+                req["t_first"] = req["t_last"] = now
+                self.stats["tokens"] += 1
+                self.stats["ok"] += bool(ok[row])
+                if s.strict and not ok[row]:
+                    self.stats["fallbacks"] += 1
+                if self._mirror_done(req):
+                    retire(req, slot)
+
+        def emit_decode(arrs, snapshot, window, t_issue) -> None:
+            toks, oks, emitted, widths = arrs
+            # per-token wall EWMA — the slo scheduler's window-cost
+            # estimate (includes pipeline overlap: a consistent,
+            # slightly pessimistic feedback signal)
+            dt_ms = (time.perf_counter() - t_issue) * 1e3 / window
+            self._itl_ms = (dt_ms if self._itl_ms == 0.0
+                            else 0.7 * self._itl_ms + 0.3 * dt_ms)
+            self._bin_widths(widths, emitted)
+            now = time.perf_counter()
+            for t in range(window):
+                for slot in range(nslots):
+                    if not emitted[t, slot]:
+                        continue
+                    rid = snapshot[slot]
+                    if rid is None:  # defensive: device-only slot
+                        continue
                     req = reqs[rid]
-                    req["out"].append(int(nxt[row]))
-                    req["ok"] += bool(ok[row])
-                    req["t_first"] = req["t_last"] = now
+                    req["out"].append(int(toks[t, slot]))
+                    req["ok"] += bool(oks[t, slot])
+                    req["t_last"] = now
                     self.stats["tokens"] += 1
-                    self.stats["ok"] += bool(ok[row])
-                    if s.strict and not ok[row]:
+                    self.stats["ok"] += bool(oks[t, slot])
+                    if s.strict and not oks[t, slot]:
                         self.stats["fallbacks"] += 1
                     if self._mirror_done(req):
                         retire(req, slot)
-            else:  # decode window
-                _, arrs, snapshot, window, t_issue = entry
-                toks, oks, emitted, widths = (np.asarray(a) for a in arrs)
-                self.stats["decode_s"] += time.perf_counter() - t0
-                # per-token wall EWMA — the slo scheduler's window-cost
-                # estimate (includes pipeline overlap: a consistent,
-                # slightly pessimistic feedback signal)
-                dt_ms = (time.perf_counter() - t_issue) * 1e3 / window
-                self._itl_ms = (dt_ms if self._itl_ms == 0.0
-                                else 0.7 * self._itl_ms + 0.3 * dt_ms)
-                self._bin_widths(widths, emitted)
-                now = time.perf_counter()
-                for t in range(window):
-                    for slot in range(nslots):
-                        if not emitted[t, slot]:
-                            continue
-                        rid = snapshot[slot]
-                        if rid is None:  # defensive: device-only slot
-                            continue
-                        req = reqs[rid]
-                        req["out"].append(int(toks[t, slot]))
-                        req["ok"] += bool(oks[t, slot])
-                        req["t_last"] = now
-                        self.stats["tokens"] += 1
-                        self.stats["ok"] += bool(oks[t, slot])
-                        if s.strict and not oks[t, slot]:
-                            self.stats["fallbacks"] += 1
-                        if self._mirror_done(req):
-                            retire(req, slot)
 
         while len(results) < len(prompts):
             now = time.perf_counter()
@@ -662,60 +720,26 @@ class Server:
             # 1) streaming admission: whenever a slot AND (paged) blocks
             # free up, in scheduler order — one batched-prefill dispatch
             if waiting and free:
-                free.sort()
-                batch: list[int] = []
-                slots_h: list[int] = []
-                rows: list[np.ndarray] = []
-                for rid in self.sched.order(waiting, reqs, now):
-                    if not free:
-                        break
-                    req = reqs[rid]
-                    if self.alloc is not None:
-                        if not self.alloc.can_alloc(req["pages_needed"]):
-                            self.stats["block_stalls"] += 1
-                            if self.sched.skip_blocked:
-                                continue  # smaller requests may still fit
-                            break  # fifo: strict head-of-line order
-                        req["blocks"] = self.alloc.alloc(req["pages_needed"])
-                        rows.append(paging.page_row(self.spec, req["blocks"]))
-                    batch.append(rid)
-                    slots_h.append(free.pop(0))
+                with _span("serve.admit") as span:
+                    batch, slots_h, host = admit(now)
+                    if batch and _span.is_enabled():
+                        span.set_metadata(rids=" ".join(map(str, batch)))
                 if batch:
-                    t_admit = time.perf_counter()
-                    for rid, slot in zip(batch, slots_h):
-                        waiting.remove(rid)
-                        slot_req[slot] = rid
-                        reqs[rid]["t_admit"] = t_admit
-                    lp = _bucket(max(len(reqs[r]["prompt"]) for r in batch),
-                                 s.prefill_chunk)
-                    tokens = np.zeros((nslots, lp), np.int32)
-                    lengths = np.ones((nslots,), np.int32)
-                    slots = np.full((nslots,), nslots, np.int32)  # pad rows
-                    rids = np.full((nslots,), -1, np.int32)
-                    for row, (rid, slot) in enumerate(zip(batch, slots_h)):
-                        p = reqs[rid]["prompt"]
-                        tokens[row, : len(p)] = p
-                        lengths[row] = len(p)
-                        slots[row] = slot
-                        rids[row] = rid
-                    pages_arg = None
-                    if self.spec is not None:
-                        pg = np.full((nslots, self.spec.n_pages),
-                                     self.spec.sentinel, np.int32)
-                        for row, pr in enumerate(rows):
-                            pg[row] = pr
-                        pages_arg = jnp.asarray(pg)
-                    cache, state, nxt, ok = self.prefill_fn(
-                        self.params, cache, state, jnp.asarray(tokens),
-                        jnp.asarray(lengths), jnp.asarray(slots),
-                        jnp.asarray(rids), base_key, self.index, pages_arg,
-                    )
+                    tokens, lengths, slots, rids, pages = host
+                    with _span("serve.prefill"):
+                        cache, state, nxt, ok = self.prefill_fn(
+                            self.params, cache, state, jnp.asarray(tokens),
+                            jnp.asarray(lengths), jnp.asarray(slots),
+                            jnp.asarray(rids), base_key, self.index,
+                            None if pages is None else jnp.asarray(pages),
+                        )
                     pending.append(("prefill", (nxt, ok), batch, slots_h))
                     self.stats["prefill_dispatches"] += 1
                     self.stats["steps"] += 1
                     self.stats["prefill_tokens"] += int(
                         sum(len(reqs[r]["prompt"]) for r in batch)
                     )
+                    self.stats["prefill_positions"] += tokens.size
                     # re-sample: occupancy/block gauges peak right after
                     # admission fills slots, not at next loop-top (by which
                     # point a uniform wave may have retired in lockstep)
@@ -724,14 +748,15 @@ class Server:
             # picked per dispatch (slo: shrinks under TTFT pressure)
             live = any(r is not None for r in slot_req)
             if live:
-                window = self.sched.pick_window(
-                    waiting, reqs, now, self._itl_ms, self._windows
-                )
-                t_issue = time.perf_counter()
-                cache, state, toks, oks, emitted, widths = self.step_fn(
-                    self.params, cache, state, base_key, np.int32(window),
-                    self.index, self.router,
-                )
+                with _span("serve.decode"):
+                    window = self.sched.pick_window(
+                        waiting, reqs, now, self._itl_ms, self._windows
+                    )
+                    t_issue = time.perf_counter()
+                    cache, state, toks, oks, emitted, widths = self.step_fn(
+                        self.params, cache, state, base_key,
+                        np.int32(window), self.index, self.router,
+                    )
                 pending.append(("decode", (toks, oks, emitted, widths),
                                 list(slot_req), window, t_issue))
                 self.stats["decode_dispatches"] += 1
@@ -743,9 +768,10 @@ class Server:
                 if not due:
                     break  # nothing left to dispatch: drain below
                 # idle until the next open-loop arrival
-                time.sleep(max(0.0, min(
-                    due[0][0] - time.perf_counter(), 0.05
-                )))
+                with _span("serve.idle"):
+                    time.sleep(max(0.0, min(
+                        due[0][0] - time.perf_counter(), 0.05
+                    )))
 
         while pending:
             process(pending.popleft())

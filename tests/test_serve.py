@@ -1,6 +1,8 @@
 """Serving-engine behaviour: batched prefill + fused decode vs the
 single-step reference loop, continuous-batching semantics, admission
 control, and the engine's observability fields."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -231,3 +233,81 @@ def test_serve_config_validation():
         Server(cfg, params, ServeConfig(overlength="explode"))
     with pytest.raises(ValueError):
         Server(cfg, params, ServeConfig(decode_window=0))
+
+
+def test_prefill_positions_count_what_prefill_computes():
+    """Each prefill dispatch computes batch_slots x its prompt bucket
+    positions, real tokens and padding alike."""
+    cfg, params = _mk(vocab=512)
+    srv = Server(cfg, params, ServeConfig(
+        batch_slots=2, max_seq=128, max_new_tokens=4, decode_window=2,
+        prefill_chunk=16))
+    srv.run([[1] * 5, [2] * 20])  # admitted together: bucket 32
+    assert srv.stats["prefill_dispatches"] == 1
+    assert srv.stats["prefill_positions"] == 2 * 32
+    srv.run([[3] * 40])  # bucket 48
+    assert srv.stats["prefill_positions"] == 2 * 32 + 2 * 48
+    assert srv.stats["prefill_tokens"] == 5 + 20 + 40
+
+
+def _program_scopes(compiled) -> set[str]:
+    """Components of the compiled program's op names, transform wrappers
+    (``vmap(...)``) dropped."""
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    return {re.sub(r"^(?:[\w.-]*\()+|\)+$", "", c)
+            for n in names for c in n.split("/")}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_step_programs_carry_layer_scopes(program):
+    """The trunk's attention and MLP and the amortized head are named in
+    the compiled serving programs, where a device trace finds them."""
+    cfg, params = _mk(vocab=4096, head_mode="amortized")
+    srv = Server(cfg, params, ServeConfig(
+        batch_slots=2, max_seq=64, max_new_tokens=4, decode_window=2))
+    state = jax.eval_shape(srv._fresh_state)
+    if program == "decode":
+        lowered = srv.step_fn.lower(
+            srv.params, srv.cache, state, srv.key,
+            jax.ShapeDtypeStruct((), jnp.int32), srv.index, srv.router)
+    else:
+        rows = jax.ShapeDtypeStruct((2,), jnp.int32)
+        lowered = srv.prefill_fn.lower(
+            srv.params, srv.cache, state,
+            jax.ShapeDtypeStruct((2, 32), jnp.int32), rows, rows, rows,
+            srv.key, srv.index, None)
+    assert {"attention", "mlp", "head"} <= _program_scopes(lowered.compile())
+
+
+def test_engine_loop_writes_host_spans(tmp_path):
+    """One span per loop phase on the profiler's clock: admissions with
+    their request ids, dispatches, syncs, bookkeeping and idle waits; no
+    more bookkeeping spans than dispatches (none per token)."""
+    from jax.profiler import ProfileData
+
+    cfg, params = _mk(vocab=512)
+    srv = Server(cfg, params, ServeConfig(
+        batch_slots=2, max_seq=64, max_new_tokens=6, decode_window=2))
+    prompts = _prompts(cfg, 3)
+    srv.run(prompts[:1])  # compiles both programs
+    srv.stats["prefill_dispatches"] = srv.stats["decode_dispatches"] = 0
+    with jax.profiler.trace(str(tmp_path)):
+        # the engine idles before the first arrival
+        srv.run(prompts, arrivals=[0.2, 0.2, 0.3])
+    dispatches = (srv.stats["prefill_dispatches"]
+                  + srv.stats["decode_dispatches"])
+    [path] = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans: dict[str, list] = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans.setdefault(ev.name, []).append(dict(ev.stats))
+    assert set(spans) == {"serve.admit", "serve.prefill", "serve.decode",
+                          "serve.sync", "serve.emit", "serve.idle"}
+    admitted = sorted(int(r) for st in spans["serve.admit"]
+                      for r in str(st.get("rids", "")).split())
+    assert admitted == [0, 1, 2]
+    assert len(spans["serve.emit"]) == len(spans["serve.sync"]) == dispatches
+    assert len(spans["serve.prefill"]) + len(spans["serve.decode"]) == (
+        dispatches)
