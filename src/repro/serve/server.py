@@ -11,10 +11,13 @@ argument, so a hot-swap (e.g. after a model push, via
 Engine (``ServeConfig.engine="pipelined"``, the default):
 
 * **Batched prefill** — admitted prompts are right-padded to a chunk
-  bucket and run through ``Model.prefill_into_cache`` in ONE dispatch that
-  writes each prompt's KV/SSM state directly into its slot's cache and
-  samples the first output token. A 500-token prompt costs one dispatch,
-  not 500.
+  bucket and run through ``Model.prefill_into_cache``, which writes each
+  prompt's KV/SSM state directly into its slot's cache and samples the
+  first output token. A 500-token prompt costs one dispatch, not 500.
+  Each dispatch holds only as many rows as its bucket needs to keep the
+  weight matmuls compute-bound (``_prefill_rows``), not every slot: the
+  admitted prompts are cut, in scheduler order, into chunks of that many
+  rows, one dispatch each, so every bucket has one prefill program.
 * **Fused decode** — a ``lax.scan`` decodes ``decode_window`` tokens per
   dispatch with per-slot active masks and on-device EOS/length-budget
   detection, amortizing dispatch + host-sync cost ``T``-fold while keeping
@@ -157,6 +160,22 @@ def _bucket(n: int, chunk: int) -> int:
     return -(-out // 512) * 512
 
 
+# Positions a prefill dispatch should hold. A bf16 weight matmul on the
+# TPU v5e turns compute-bound at 197 TFLOP/s / 819 GB/s ≈ 240 rows per
+# weight read, rounded to 256: a smaller dispatch would wait on the
+# weights, a larger one would compute padding for nothing.
+_PREFILL_POSITIONS = 256
+
+
+def _prefill_rows(lp: int, nslots: int, multiple: int = 1) -> int:
+    """Rows of the prefill dispatch for prompt bucket ``lp``: enough for
+    ``_PREFILL_POSITIONS`` positions, at least one, at most ``nslots``,
+    rounded up to ``multiple`` (the mesh's batch shards, which divide
+    ``nslots``). One count per bucket, so one program per bucket."""
+    rows = min(nslots, max(1, -(-_PREFILL_POSITIONS // lp)))
+    return min(nslots, -(-rows // multiple) * multiple)
+
+
 class Server:
     def __init__(self, cfg: ArchConfig, params, scfg: ServeConfig, mesh=None):
         if scfg.engine not in ("pipelined", "reference"):
@@ -238,6 +257,9 @@ class Server:
         # previous dispatch's GSPMD-placed outputs hash as different jit
         # signatures and every run would recompile the engine steps
         self._cache_sh = self._state_sh = None
+        # prefill rows come in multiples of the cache's batch shards, so
+        # that a short prefill still splits across them
+        self._row_multiple = 1
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -254,6 +276,10 @@ class Server:
             if scfg.paged:
                 state_keys.append("pages")
             self._state_sh = {k: rep for k in state_keys}
+            shards = int(np.prod([mesh.shape[a]
+                                  for a in mesh_lib.fsdp_axes(mesh)]))
+            if scfg.batch_slots % shards == 0:
+                self._row_multiple = shards
 
         def _pin(cache, state):
             if self._cache_sh is None:
@@ -303,8 +329,8 @@ class Server:
             "steps": 0, "tokens": 0, "ok": 0, "fallbacks": 0,
             "prefill_dispatches": 0, "decode_dispatches": 0,
             "prefill_tokens": 0, "rejected": 0,
-            # positions the prefill programs computed: batch_slots x the
-            # prompt bucket per dispatch (prefill_tokens of them are real)
+            # positions the prefill programs computed: rows x the prompt
+            # bucket per dispatch (prefill_tokens of them are real)
             "prefill_positions": 0,
             # adaptive probe: emitted-token counts per effective probe
             # width {width: count} — empty on fixed-width serving
@@ -608,14 +634,46 @@ class Server:
             slot_req[slot] = None
             free.append(slot)
 
+        def rows_for(lp: int) -> int:
+            return _prefill_rows(lp, nslots, self._row_multiple)
+
+        def host_chunk(batch, slots_h):
+            """Host arrays (tokens, lengths, slots, rids, pages or None) of
+            one prefill dispatch: ``rows_for(lp)`` rows of the chunk's
+            bucket ``lp``, pad rows at slot ``nslots`` and rid -1."""
+            lp = _bucket(max(len(reqs[r]["prompt"]) for r in batch),
+                         s.prefill_chunk)
+            n = rows_for(lp)
+            tokens = np.zeros((n, lp), np.int32)
+            lengths = np.ones((n,), np.int32)
+            slots = np.full((n,), nslots, np.int32)  # pad rows
+            rids = np.full((n,), -1, np.int32)
+            for row, (rid, slot) in enumerate(zip(batch, slots_h)):
+                p = reqs[rid]["prompt"]
+                tokens[row, : len(p)] = p
+                lengths[row] = len(p)
+                slots[row] = slot
+                rids[row] = rid
+            pages = None
+            if self.spec is not None:
+                pages = np.full((n, self.spec.n_pages), self.spec.sentinel,
+                                np.int32)
+                for row, rid in enumerate(batch):
+                    pages[row] = paging.page_row(self.spec,
+                                                 reqs[rid]["blocks"])
+            return tokens, lengths, slots, rids, pages
+
         def admit(now):
-            """Scheduler order, block allocation and the host batch: (the
-            admitted rids, their slots, host arrays (tokens, lengths,
-            slots, rids, pages or None)), or ([], [], None)."""
+            """Scheduler order and block allocation, then the admitted
+            requests cut into prefill chunks, in scheduler order: a
+            request joins the open chunk if the chunk, with it, has no
+            more rows than ``rows_for`` gives for the bucket of its
+            longest prompt; otherwise it opens the next chunk. A list of
+            (rids, slots, host arrays) per prefill dispatch, empty if
+            none was admitted."""
             free.sort()
             batch: list[int] = []
             slots_h: list[int] = []
-            rows: list[np.ndarray] = []
             for rid in self.sched.order(waiting, reqs, now):
                 if not free:
                     break
@@ -627,35 +685,24 @@ class Server:
                             continue  # smaller requests may still fit
                         break  # fifo: strict head-of-line order
                     req["blocks"] = self.alloc.alloc(req["pages_needed"])
-                    rows.append(paging.page_row(self.spec, req["blocks"]))
                 batch.append(rid)
                 slots_h.append(free.pop(0))
-            if not batch:
-                return batch, slots_h, None
             t_admit = time.perf_counter()
+            chunks: list[tuple[list[int], list[int]]] = []
+            lp = 0  # the open chunk's bucket
             for rid, slot in zip(batch, slots_h):
                 waiting.remove(rid)
                 slot_req[slot] = rid
                 reqs[rid]["t_admit"] = t_admit
-            lp = _bucket(max(len(reqs[r]["prompt"]) for r in batch),
-                         s.prefill_chunk)
-            tokens = np.zeros((nslots, lp), np.int32)
-            lengths = np.ones((nslots,), np.int32)
-            slots = np.full((nslots,), nslots, np.int32)  # pad rows
-            rids = np.full((nslots,), -1, np.int32)
-            for row, (rid, slot) in enumerate(zip(batch, slots_h)):
-                p = reqs[rid]["prompt"]
-                tokens[row, : len(p)] = p
-                lengths[row] = len(p)
-                slots[row] = slot
-                rids[row] = rid
-            pages = None
-            if self.spec is not None:
-                pages = np.full((nslots, self.spec.n_pages),
-                                self.spec.sentinel, np.int32)
-                for row, pr in enumerate(rows):
-                    pages[row] = pr
-            return batch, slots_h, (tokens, lengths, slots, rids, pages)
+                b = _bucket(len(reqs[rid]["prompt"]), s.prefill_chunk)
+                if chunks and len(chunks[-1][0]) < rows_for(max(lp, b)):
+                    lp = max(lp, b)
+                else:
+                    chunks.append(([], []))
+                    lp = b
+                chunks[-1][0].append(rid)
+                chunks[-1][1].append(slot)
+            return [(c, sl, host_chunk(c, sl)) for c, sl in chunks]
 
         def process(entry) -> None:
             kind = entry[0]
@@ -718,13 +765,15 @@ class Server:
                 waiting.append(due.popleft()[1])
             self._gauges(len(waiting) + len(due), slot_req)
             # 1) streaming admission: whenever a slot AND (paged) blocks
-            # free up, in scheduler order — one batched-prefill dispatch
+            # free up, in scheduler order — one batched-prefill dispatch a
+            # chunk
             if waiting and free:
                 with _span("serve.admit") as span:
-                    batch, slots_h, host = admit(now)
-                    if batch and _span.is_enabled():
-                        span.set_metadata(rids=" ".join(map(str, batch)))
-                if batch:
+                    chunks = admit(now)
+                    if chunks and _span.is_enabled():
+                        span.set_metadata(rids=" ".join(
+                            str(r) for c in chunks for r in c[0]))
+                for batch, slots_h, host in chunks:
                     tokens, lengths, slots, rids, pages = host
                     with _span("serve.prefill"):
                         cache, state, nxt, ok = self.prefill_fn(
@@ -740,6 +789,7 @@ class Server:
                         sum(len(reqs[r]["prompt"]) for r in batch)
                     )
                     self.stats["prefill_positions"] += tokens.size
+                if chunks:
                     # re-sample: occupancy/block gauges peak right after
                     # admission fills slots, not at next loop-top (by which
                     # point a uniform wave may have retired in lockstep)

@@ -11,7 +11,7 @@ import pytest
 import repro.models.transformer as T
 from repro.configs import get_smoke
 from repro.models.model import Model
-from repro.serve.server import ServeConfig, Server, _bucket
+from repro.serve.server import ServeConfig, Server, _bucket, _prefill_rows
 
 
 @pytest.fixture(autouse=True)
@@ -30,6 +30,11 @@ def _prompts(cfg, n, lo=3, hi=11, seed=0):
     rng = np.random.default_rng(seed)
     return [list(rng.integers(0, cfg.vocab, size=int(rng.integers(lo, hi))))
             for _ in range(n)]
+
+
+def _prompts_of(cfg, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [list(rng.integers(0, cfg.vocab, size=n)) for n in lengths]
 
 
 # ------------------------------------------------------------ equivalence
@@ -236,8 +241,8 @@ def test_serve_config_validation():
 
 
 def test_prefill_positions_count_what_prefill_computes():
-    """Each prefill dispatch computes batch_slots x its prompt bucket
-    positions, real tokens and padding alike."""
+    """Each prefill dispatch computes rows x its prompt bucket positions,
+    real tokens and padding alike."""
     cfg, params = _mk(vocab=512)
     srv = Server(cfg, params, ServeConfig(
         batch_slots=2, max_seq=128, max_new_tokens=4, decode_window=2,
@@ -248,6 +253,66 @@ def test_prefill_positions_count_what_prefill_computes():
     srv.run([[3] * 40])  # bucket 48
     assert srv.stats["prefill_positions"] == 2 * 32 + 2 * 48
     assert srv.stats["prefill_tokens"] == 5 + 20 + 40
+
+
+def test_prefill_rows_per_bucket():
+    """256 positions a dispatch, one row at least, every slot at most,
+    in multiples of the mesh's batch shards."""
+    assert [_prefill_rows(lp, 32) for lp in (128, 256, 384)] == [2, 1, 1]
+    assert _prefill_rows(32, 32) == 8
+    assert _prefill_rows(16, 4) == 4
+    assert _prefill_rows(1024, 32) == 1
+    assert _prefill_rows(128, 32, multiple=4) == 4
+    assert _prefill_rows(16, 2, multiple=2) == 2
+
+
+def test_lone_long_prompt_prefills_one_row():
+    """A lone 200-token prompt (bucket 256) is prefilled in one row, not
+    in every slot."""
+    cfg, params = _mk(vocab=512)
+    srv = Server(cfg, params, ServeConfig(
+        batch_slots=4, max_seq=256, max_new_tokens=4, decode_window=2,
+        prefill_chunk=16))
+    (r,) = srv.run([[1] * 200])
+    assert len(r.tokens) == 4
+    assert srv.stats["prefill_dispatches"] == 1
+    assert srv.stats["prefill_positions"] == 1 * 256
+
+
+def test_short_prompts_share_one_dispatch():
+    """Four 10-token prompts admitted together fit R(16) = 4 rows: one
+    dispatch, no pad row."""
+    cfg, params = _mk(vocab=512)
+    srv = Server(cfg, params, ServeConfig(
+        batch_slots=4, max_seq=64, max_new_tokens=4, decode_window=2,
+        prefill_chunk=16))
+    rs = srv.run([[i + 1] * 10 for i in range(4)])
+    assert all(len(r.tokens) == 4 for r in rs)
+    assert srv.stats["prefill_dispatches"] == 1
+    assert srv.stats["prefill_positions"] == 4 * 16
+
+
+@pytest.mark.parametrize("block_len", [0, 16], ids=["dense", "paged"])
+def test_prefill_chunks_match_reference_bitwise(block_len):
+    """A burst wider than its buckets' row counts is cut into chunks in
+    scheduler order and still samples the reference loop's tokens. Two
+    waves of four (slots 4, every request the same length budget):
+    [100, 10 | 120, 20] give (3, 112) and (2, 128); [200 | 30, 60, 70]
+    give (1, 256) and (4, 80): four dispatches, one program a bucket."""
+    cfg, params = _mk(vocab=512)
+    prompts = _prompts_of(cfg, [100, 10, 120, 20, 200, 30, 60, 70])
+    base = dict(batch_slots=4, max_seq=256, max_new_tokens=4, seed=3)
+    ref = Server(cfg, params, ServeConfig(engine="reference", **base))
+    srv = Server(cfg, params, ServeConfig(
+        decode_window=2, prefill_chunk=16, block_len=block_len, **base))
+    r_ref, r_srv = ref.run(prompts), srv.run(prompts)
+    assert all(len(r.tokens) == 4 for r in r_srv)
+    assert [r.tokens for r in r_srv] == [r.tokens for r in r_ref]
+    assert srv.stats["prefill_dispatches"] == 4
+    assert srv.stats["prefill_positions"] == (3 * 112 + 2 * 128 + 1 * 256
+                                              + 4 * 80)
+    assert srv.stats["prefill_tokens"] == sum(map(len, prompts))
+    assert srv.prefill_fn._cache_size() == 4
 
 
 def _program_scopes(compiled) -> set[str]:
