@@ -8,6 +8,7 @@ from repro.models.config import ArchConfig
 _MODULES = {
     "mixtral-8x22b": "mixtral_8x22b",
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "stablelm-3b": "stablelm_3b",
     "granite-8b": "granite_8b",
     "tinyllama-1.1b": "tinyllama_1_1b",

@@ -19,6 +19,14 @@ Precision: ``q·k`` multiplies the operands in their storage dtype (bf16
 products are exact in the f32 accumulator, so one MXU pass loses
 nothing); ``p·v`` keeps the f32 softmax weights at full precision.
 
+Latent caches (MLA's absorbed decode, models/attention.mla_decode): with
+``v_width`` and no ``v_cache``, one KV "head" serves every query head and
+its values are the first ``v_width`` columns of the very rows read for
+the keys, so the latent cache is read once. There ``p·v`` multiplies the
+softmax weights rounded to the cache's dtype, one MXU pass: with all 16
+query heads of a slot on 512 value columns, six f32 passes would make
+the kernel compute-bound.
+
 :func:`flash_prefill` is the causal prompt-side twin: a block of prompt
 positions attends over the ring a token-by-token decode would hold, each
 row over its own prefix, through the same per-tile step
@@ -49,11 +57,13 @@ def _init_run(m_run, s_run, acc):
 
 
 def _online_step(q, k_ref, v_ref, lens, j, m_run, s_run, acc, *, scale,
-                 s_blk):
+                 s_blk, v_width=None):
     """One ``(s_blk, hd)`` KV tile of the online softmax for query rows
     ``q`` (n, hd); row ``r`` sees the cache slots ``< lens[r]`` ((n, 1)).
     Each row's arithmetic is independent of the other rows — the decode
-    and prefill kernels rest their bitwise agreement on it."""
+    and prefill kernels rest their bitwise agreement on it. With
+    ``v_width`` the values are the tile's first ``v_width`` key columns
+    (``v_ref`` unused)."""
     k = k_ref[...]  # (s_blk, hd)
     scores = jax.lax.dot_general(
         q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
@@ -69,6 +79,12 @@ def _online_step(q, k_ref, v_ref, lens, j, m_run, s_run, acc, *, scale,
     p = jnp.exp(scores - m_new)  # (n, s_blk)
     m_run[...] = m_new
     s_run[...] = s_run[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    if v_width is not None:
+        acc[...] = acc[...] * corr + jnp.dot(
+            p.astype(k.dtype), k[:, :v_width],
+            preferred_element_type=jnp.float32,
+        )
+        return
     acc[...] = acc[...] * corr + jnp.dot(
         p, v_ref[...].astype(jnp.float32), precision=_HI,
         preferred_element_type=jnp.float32,
@@ -76,7 +92,7 @@ def _online_step(q, k_ref, v_ref, lens, j, m_run, s_run, acc, *, scale,
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_run, s_run, acc, *, scale,
-            s_blk):
+            s_blk, v_width=None):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -86,33 +102,45 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_run, s_run, acc, *, scale,
 
     lens = jnp.full(m_run.shape, len_ref[b], jnp.int32)
     _online_step(q_ref[...], k_ref, v_ref, lens, j, m_run, s_run, acc,
-                 scale=scale, s_blk=s_blk)
+                 scale=scale, s_blk=s_blk, v_width=v_width)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         o_ref[...] = acc[...] / s_run[...]
 
 
+def _latent_kernel(len_ref, q_ref, k_ref, o_ref, m_run, s_run, acc, *,
+                   scale, s_blk, v_width):
+    _kernel(len_ref, q_ref, k_ref, None, o_ref, m_run, s_run, acc,
+            scale=scale, s_blk=s_blk, v_width=v_width)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("s_block", "interpret")
+    jax.jit, static_argnames=("s_block", "interpret", "v_width", "scale")
 )
 def flash_decode(
     q: jax.Array,  # (B, Hq, hd) — one query token per sequence
     k_cache: jax.Array,  # (B, Hkv, S, hd), head-major
-    v_cache: jax.Array,  # (B, Hkv, S, hd)
+    v_cache: jax.Array | None,  # (B, Hkv, S, hd); None: latent (v_width)
     lengths: jax.Array,  # (B,) int32 valid cache lengths
     *,
     s_block: int = 512,
     interpret: bool = True,
+    v_width: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Returns attention output (B, Hq, hd), f32: query ``i`` attends over
-    the first ``lengths[i]`` slots of its cache row."""
+    the first ``lengths[i]`` slots of its cache row. A latent cache
+    (``v_cache`` None) gives (B, Hq, v_width), the values being the first
+    ``v_width`` columns of the key rows. ``scale`` defaults to
+    ``hd ** -0.5``."""
     b, hq, hd = q.shape
     _, hkv, s, _ = k_cache.shape
     g = hq // hkv  # GQA group size
     s_blk = min(s_block, s)
     assert s % s_blk == 0, (s, s_blk)
-    scale = 1.0 / (hd**0.5)
+    if scale is None:
+        scale = 1.0 / (hd**0.5)
     grid = (b, hkv, s // s_blk)
     kv_spec = pl.BlockSpec(
         (None, None, s_blk, hd), lambda i, h, j, lens: (i, h, j, 0)
@@ -120,24 +148,36 @@ def flash_decode(
     q_spec = pl.BlockSpec(
         (None, None, g, hd), lambda i, h, j, lens: (i, h, 0, 0)
     )
+    if v_cache is None:  # latent: values are K's first v_width columns
+        assert v_width is not None and v_width <= hd, (v_width, hd)
+        kernel = functools.partial(_latent_kernel, v_width=v_width)
+        in_specs, operands, hv = [q_spec, kv_spec], (k_cache,), v_width
+        out_spec = pl.BlockSpec(
+            (None, None, g, hv), lambda i, h, j, lens: (i, h, 0, 0)
+        )
+    else:
+        kernel = _kernel
+        in_specs, operands, hv = [q_spec, kv_spec, kv_spec], (
+            k_cache, v_cache), hd
+        out_spec = q_spec
 
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, s_blk=s_blk),
+        functools.partial(kernel, scale=scale, s_blk=s_blk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
+            in_specs=in_specs,
+            out_specs=out_spec,
             scratch_shapes=[
                 pltpu.VMEM((g, 1), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
+                pltpu.VMEM((g, hv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hv), jnp.float32),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q.reshape(b, hkv, g, hd), k_cache, v_cache)
-    return out.reshape(b, hq, hd)
+    )(lengths.astype(jnp.int32), q.reshape(b, hkv, g, hd), *operands)
+    return out.reshape(b, hq, hv)
 
 
 def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_run, s_run, acc, *, scale,

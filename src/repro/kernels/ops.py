@@ -126,16 +126,20 @@ def fused_estimator(emb, ids, h, log_w):
     return _fused_estimator(emb, ids, h, log_w, interpret=resolve_interpret())
 
 
-def flash_decode(q, k_cache, v_cache, lengths, *, s_block: int = 512):
+def flash_decode(q, k_cache, v_cache, lengths, *, s_block: int = 512,
+                 v_width: int | None = None, scale: float | None = None):
+    """``v_cache`` None: a latent cache whose values are the first
+    ``v_width`` columns of ``k_cache`` (see kernels/flash_decode.py)."""
     if OPAQUE_STUBS:
+        hv = q.shape[-1] if v_cache is not None else v_width
         return _stub(
             "flash_decode",
-            jax.ShapeDtypeStruct(q.shape, jnp.float32),
-            q, k_cache, v_cache, lengths,
+            jax.ShapeDtypeStruct(q.shape[:-1] + (hv,), jnp.float32),
+            *(a for a in (q, k_cache, v_cache, lengths) if a is not None),
         )
     return _flash_decode(
         q, k_cache, v_cache, lengths, s_block=s_block,
-        interpret=resolve_interpret(),
+        interpret=resolve_interpret(), v_width=v_width, scale=scale,
     )
 
 

@@ -131,9 +131,11 @@ def tail_gather_argmax_ref(emb, pos, m_used, pert_s, s_ids, heights, h):
 
 
 def flash_decode_ref(
-    q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array
+    q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, lengths: jax.Array,
+    scale: float | None = None,
 ) -> jax.Array:
-    """(B,Hq,hd), head-major (B,Hkv,S,hd) x2, (B,) -> (B,Hq,hd)."""
+    """(B,Hq,hd), head-major (B,Hkv,S,hd) and (B,Hkv,S,hv), (B,) ->
+    (B,Hq,hv); ``scale`` defaults to ``hd ** -0.5``."""
     b, hq, hd = q.shape
     _, hkv, s, _ = k_cache.shape
     g = hq // hkv
@@ -143,7 +145,8 @@ def flash_decode_ref(
     # expand KV heads to query heads
     kf = jnp.repeat(kf, g, axis=1)  # (B, Hq, S, hd)
     vf = jnp.repeat(vf, g, axis=1)
-    scores = jnp.einsum("bhd,bhsd->bhs", qf, kf) / (hd**0.5)
+    scores = jnp.einsum("bhd,bhsd->bhs", qf, kf)
+    scores = scores / (hd**0.5) if scale is None else scores * scale
     mask = jnp.arange(s)[None, None, :] < lengths[:, None, None]
     scores = jnp.where(mask, scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)

@@ -171,7 +171,10 @@ def cache_shardings(cache_shapes: Any, mesh, cfg, paged: bool = False) -> Any:
         shape = leaf.shape  # leading dim = layer stack
         spec = [None] * len(shape)
         name = keys[-1]
-        pool_leaf = paged and name in ("k", "v") and len(shape) == 5
+        # ckv: MLA's latent rings (layers, B, S, w) or pool (layers, nb,
+        # bl, w), no head axis
+        pool_leaf = paged and (
+            (name in ("k", "v") and len(shape) == 5) or name == "ckv")
         if len(shape) >= 2 and not pool_leaf:
             if fa and _dim_ok(shape[1], mesh, fa):
                 spec[1] = fa  # batch

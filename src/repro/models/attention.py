@@ -8,6 +8,19 @@ over query blocks — the (L, L) score matrix never materializes, which is
 what lets 32k-token prefill compile within HBM budgets. Sliding-window
 layers slice only the ``window + q_block`` KV span per query block, making
 SWA genuinely sub-quadratic (not just masked).
+
+Multi-head latent attention (MLA, DeepSeek-V2 arXiv:2405.04434) is the
+second attention kind (``cfg.attn_kind == "mla"``). Each position caches
+one latent row ``[c_kv | k_pe]``: the RMS-normed down-projection c_kv
+and one rotary key shared by every head (zero-padded to a multiple of
+128 wide, the width the TPU stores it at). Training and prefill attend in
+the expanded form: per-head keys ``[c_kv·W_UK,h | k_pe]`` and values
+``c_kv·W_UV,h``, whose widths differ. Decode attends in the absorbed
+form: the query folds ``W_UK,h`` in (``q̃_h = W_UK,h·q_nope_h``), the
+scores are ``[q̃_h | q_pe_h]·[c_kv | k_pe]``, and the output ``Σ p·c_kv``
+goes through ``W_UV,h`` — multi-query attention with one KV "head" as
+wide as the latent row, whose values are its first ``kv_lora_rank``
+columns, read by the same ``flash_decode`` kernel as GQA.
 """
 from __future__ import annotations
 
@@ -17,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ArchConfig
-from repro.models.layers import dense_init, rope
+from repro.models.layers import dense_init, rms_norm, rope
 
 _NEG = -1e30
 
@@ -33,6 +46,8 @@ SCORES_DTYPE = "f32"
 
 
 def init(key, cfg: ArchConfig) -> dict:
+    if cfg.attn_kind == "mla":
+        return mla_init(key, cfg)
     d = cfg.d_model
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
@@ -103,6 +118,7 @@ def blockwise_attention(
     kv_block = kv_block or KV_BLOCK
     b, l, h, hd = q.shape
     kvh = k.shape[2]
+    hv = v.shape[-1]  # value width: hd, or MLA's narrower values
     g = h // kvh
     qb = min(q_block, l)
     kb = min(kv_block, l)
@@ -128,7 +144,7 @@ def blockwise_attention(
 
         m0 = jnp.full((b, kvh, g, qb), _NEG, jnp.float32)
         s0 = jnp.zeros((b, kvh, g, qb), jnp.float32)
-        a0 = jnp.zeros((b, kvh, g, qb, hd), jnp.float32)
+        a0 = jnp.zeros((b, kvh, g, qb, hv), jnp.float32)
 
         def body(carry, ki):
             k_blk = jax.lax.dynamic_slice_in_dim(k_loc, ki * kb, kb, axis=1)
@@ -148,7 +164,7 @@ def blockwise_attention(
     outs = jax.lax.map(
         jax.checkpoint(per_qblock), (jnp.arange(l // qb), jnp.moveaxis(qg, 1, 0))
     )
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, l, h, hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, l, h, hv)
     return out.astype(q.dtype)
 
 
@@ -162,6 +178,8 @@ def forward(
     prefix: int = 0,
 ) -> jax.Array:
     """Training/prefill attention (no cache)."""
+    if cfg.attn_kind == "mla":
+        return mla_forward(p, cfg, x, positions)
     b, l, d = x.shape
     dt = x.dtype
     win = cfg.window if window is None else window
@@ -176,6 +194,32 @@ def forward(
         prefix=prefix,
     )
     return out.reshape(b, l, cfg.d_attn) @ p["wo"].astype(dt)
+
+
+def _ring_place(rows: dict, s_c: int, lengths, dt) -> dict:
+    """Each ``(B, L, KV, w)`` array of ``rows`` as the head-major ring
+    ``(B, KV, s_c, w)`` a token-by-token decode of the same prompt would
+    leave (see :func:`prefill`)."""
+    l = next(iter(rows.values())).shape[1]
+    if lengths is not None:
+        # per-row ring placement: slot j holds position
+        # p = len-1 - ((len-1-j) mod s_c), the newest valid p ≡ j (mod s_c)
+        j = jnp.arange(s_c)
+        pj = (lengths[:, None] - 1) - ((lengths[:, None] - 1 - j[None]) % s_c)
+        live = pj >= 0  # (B, s_c); rows shorter than s_c leave tail slots 0
+        pc = jnp.clip(pj, 0, l - 1)[..., None, None]
+        rings = {n: jnp.where(live[..., None, None],
+                              jnp.take_along_axis(r, pc, axis=1),
+                              0).astype(dt) for n, r in rows.items()}
+    elif l <= s_c:
+        rings = {n: jnp.zeros((r.shape[0], s_c) + r.shape[2:], dt)
+                 .at[:, :l].set(r) for n, r in rows.items()}
+    else:  # ring buffer: keep the last s_c keys at their ring slots
+        kept = jnp.arange(l - s_c, l)
+        slots = kept % s_c
+        rings = {n: jnp.zeros((r.shape[0], s_c) + r.shape[2:], dt)
+                 .at[:, slots].set(r[:, l - s_c:]) for n, r in rows.items()}
+    return {n: a.transpose(0, 2, 1, 3) for n, a in rings.items()}
 
 
 def prefill(
@@ -212,6 +256,8 @@ def prefill(
     would, and the serving engines' tokens agree. Prefix-LM, wrapping
     rings and the CPU take blockwise attention.
     """
+    if cfg.attn_kind == "mla":
+        return mla_prefill(p, cfg, x, positions, max_seq, lengths=lengths)
     b, l, d = x.shape
     dt = x.dtype
     win = cfg.window if window is None else window
@@ -222,27 +268,7 @@ def prefill(
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     s_c = min(win, max_seq) if win else max_seq
-    shape = (b, s_c, cfg.n_kv_heads, cfg.head_dim)
-    if lengths is not None:
-        # per-row ring placement: slot j holds position
-        # p = len-1 - ((len-1-j) mod s_c), the newest valid p ≡ j (mod s_c)
-        j = jnp.arange(s_c)
-        pj = (lengths[:, None] - 1) - ((lengths[:, None] - 1 - j[None]) % s_c)
-        live = pj >= 0  # (B, s_c); rows shorter than s_c leave tail slots 0
-        pc = jnp.clip(pj, 0, l - 1)[..., None, None]
-        ck = jnp.where(live[..., None, None],
-                       jnp.take_along_axis(k, pc, axis=1), 0).astype(dt)
-        cv = jnp.where(live[..., None, None],
-                       jnp.take_along_axis(v, pc, axis=1), 0).astype(dt)
-    elif l <= s_c:
-        ck = jnp.zeros(shape, dt).at[:, :l].set(k)
-        cv = jnp.zeros(shape, dt).at[:, :l].set(v)
-    else:  # ring buffer: keep the last s_c keys at their ring slots
-        kept = jnp.arange(l - s_c, l)
-        slots = kept % s_c
-        ck = jnp.zeros(shape, dt).at[:, slots].set(k[:, l - s_c :])
-        cv = jnp.zeros(shape, dt).at[:, slots].set(v[:, l - s_c :])
-    cache = {"k": ck.transpose(0, 2, 1, 3), "v": cv.transpose(0, 2, 1, 3)}
+    cache = _ring_place({"k": k, "v": v}, s_c, lengths, dt)
     from repro.kernels import ops as kops
 
     causal = cfg.causal and not cfg.encoder_only
@@ -269,6 +295,8 @@ def init_cache(
     and so the ring itself enforces the sliding window at decode time."""
     win = cfg.window if window is None else window
     s_c = min(win, max_seq) if win else max_seq
+    if cfg.attn_kind == "mla":  # one latent row a position, no head axis
+        return {"ckv": jnp.zeros((batch, s_c, _mla_width(cfg)), dtype)}
     shape = (batch, cfg.n_kv_heads, s_c, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -282,7 +310,10 @@ def init_pool(
     physical block id addresses the same block slice in every layer (the
     pool leaf carries the layer-stack axis), so one allocation covers the
     whole trunk. Blocks are head-major, ``(KV, block_len, hd)``, like the
-    dense rings."""
+    dense rings (MLA: one latent array of ``(block_len, latent)`` blocks)."""
+    if cfg.attn_kind == "mla":
+        return {"ckv": jnp.zeros((n_blocks, block_len, _mla_width(cfg)),
+                                 dtype)}
     shape = (n_blocks, cfg.n_kv_heads, block_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -294,7 +325,9 @@ def cache_bytes_per_slot(cfg: ArchConfig, max_seq: int, dtype,
     has to fit worst-case ``max_seq``)."""
     win = cfg.window if window is None else window
     s_c = min(win, max_seq) if win else max_seq
-    return 2 * s_c * cfg.n_kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize
+    per_pos = (_mla_width(cfg) if cfg.attn_kind == "mla"
+               else 2 * cfg.n_kv_heads * cfg.head_dim)
+    return s_c * per_pos * jnp.dtype(dtype).itemsize
 
 
 def decode(
@@ -387,6 +420,161 @@ def decode(
     out = o.astype(dt).reshape(b, 1, cfg.d_attn) @ p["wo"].astype(dt)
     return out, cache
 
+
+# ------------------------------------------------------------ latent (MLA)
+def mla_init(key, cfg: ArchConfig) -> dict:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(k1, (d, h * (cfg.qk_nope_dim + cfg.qk_rope_dim))),
+        "wkv_a": dense_init(k2, (d, cfg.latent_width)),  # W_DKV | W_KR
+        "kv_norm": jnp.zeros((r,), jnp.float32),
+        "wkv_b": dense_init(k3, (r, h * (cfg.qk_nope_dim + cfg.v_head_dim))),
+        "wo": dense_init(k4, (h * cfg.v_head_dim, d)),
+    }
+
+
+def _mla_width(cfg: ArchConfig) -> int:
+    """Width of a cached latent row: ``r + rope`` rounded up to 128 with
+    zeros. The TPU tiles an array's minor dim in 128s, so 576 values take
+    640 in memory either way; stored 576 wide, the array is given a
+    transposed layout instead and every decode dispatch copies the whole
+    cache into the row-major one the kernel reads."""
+    return -(-cfg.latent_width // 128) * 128
+
+
+def _mla_pad(cfg: ArchConfig, x: jax.Array) -> jax.Array:
+    """Latent-width rows (..., r + rope) zero-padded to the cached width."""
+    pad = _mla_width(cfg) - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _mla_scale(cfg: ArchConfig) -> float:
+    """Softmax scale: over the query/key head width, nope + rope."""
+    return 1.0 / (cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5
+
+
+def _mla_in(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array):
+    """The projections of ``x`` (B, L, d): the query, split as ``q_nope``
+    (B, L, H, nope) and RoPE'd ``q_pe`` (B, L, H, rope), and the latent
+    rows (B, L, r + rope) the cache holds, ``[RMSNorm(c_kv) | RoPE(k_pe)]``."""
+    b, l, _ = x.shape
+    dt = x.dtype
+    nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+    q = (x @ p["wq"].astype(dt)).reshape(b, l, cfg.n_heads, -1)
+    q_pe = rope(q[..., nope:], positions, cfg.rope_theta)
+    c = x @ p["wkv_a"].astype(dt)
+    c_kv = rms_norm(c[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope(c[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
+
+
+def _mla_up(p: dict, cfg: ArchConfig, dt) -> tuple[jax.Array, jax.Array]:
+    """W_UK (r, H, nope) and W_UV (r, H, v), the halves of W_UKV."""
+    w = p["wkv_b"].astype(dt).reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., : cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
+
+
+def _mla_expanded(p: dict, cfg: ArchConfig, q_nope, q_pe, lat) -> jax.Array:
+    """Causal attention in the expanded form over the latent rows ``lat``
+    of the same sequence: per-head keys ``[c_kv·W_UK,h | k_pe]`` (nope +
+    rope wide) and values ``c_kv·W_UV,h`` (v wide). Returns (B, L, d)."""
+    b, l, h, _ = q_nope.shape
+    dt = lat.dtype
+    r = cfg.kv_lora_rank
+    w_uk, w_uv = _mla_up(p, cfg, dt)
+    with jax.named_scope("mla.attend"):
+        k_nope = jnp.einsum("blr,rhn->blhn", lat[..., :r], w_uk)
+        v = jnp.einsum("blr,rhv->blhv", lat[..., :r], w_uv)
+        k_pe = jnp.broadcast_to(lat[:, :, None, r:r + cfg.qk_rope_dim],
+                                (b, l, h, cfg.qk_rope_dim))
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate([k_nope, k_pe], axis=-1)
+        out = blockwise_attention(q, k, v, causal=True)
+    with jax.named_scope("mla.up"):
+        return out.reshape(b, l, -1) @ p["wo"].astype(dt)
+
+
+def mla_forward(p: dict, cfg: ArchConfig, x: jax.Array,
+                positions: jax.Array) -> jax.Array:
+    """Training attention (no cache), expanded form."""
+    with jax.named_scope("mla.latent"):
+        q_nope, q_pe, lat = _mla_in(p, cfg, x, positions)
+    return _mla_expanded(p, cfg, q_nope, q_pe, lat)
+
+
+def mla_prefill(p: dict, cfg: ArchConfig, x: jax.Array, positions,
+                max_seq: int, *, lengths=None) -> tuple[jax.Array, dict]:
+    """Prompt attention in the expanded form, and the latent ring
+    ``{"ckv": (B, max_seq, w)}`` a token-by-token decode of the
+    same prompt would leave (placement as in :func:`prefill`)."""
+    with jax.named_scope("mla.latent"):
+        q_nope, q_pe, lat = _mla_in(p, cfg, x, positions)
+        ring = _ring_place({"ckv": _mla_pad(cfg, lat)[:, :, None]}, max_seq,
+                           lengths, x.dtype)["ckv"]
+        cache = {"ckv": ring[:, 0]}
+    return _mla_expanded(p, cfg, q_nope, q_pe, lat), cache
+
+
+def mla_decode(p: dict, cfg: ArchConfig, x: jax.Array, cache: dict,
+               pos: jax.Array, layer, *, use_kernel: bool | None = None,
+               pages=None, write_mask=None, mesh=None
+               ) -> tuple[jax.Array, dict]:
+    """One token in the absorbed form against layer ``layer`` of a latent
+    cache stacked over a group of layers: ``cache["ckv"]`` holds rings
+    ``(layers, B, s_c, w)`` or the paged pool ``(layers, n_blocks,
+    block_len, w)``, ``w`` the padded latent width (:func:`_mla_width`;
+    pages and ``write_mask`` as in :func:`decode`).
+    The stack is written in place, so it can ride the layer loop whole:
+    a loop that scanned over it would build a second stack as its output,
+    and a serving-size latent cache leaves no room for two."""
+    b = x.shape[0]
+    dt = x.dtype
+    r = cfg.kv_lora_rank
+    ckv = cache["ckv"]
+    with jax.named_scope("mla.latent"):
+        q_nope, q_pe, lat = _mla_in(p, cfg, x, pos[:, None])
+        lat = _mla_pad(cfg, lat)
+        if pages is None:
+            s_c = ckv.shape[2]
+            ckv = ckv.at[layer, jnp.arange(b), pos % s_c].set(lat[:, 0])
+            view = ckv[layer]
+        else:
+            n_blocks, block_len = ckv.shape[1], ckv.shape[2]
+            s_c = pages.shape[1] * block_len
+            slot = pos % s_c
+            phys = jnp.take_along_axis(
+                pages, (slot // block_len)[:, None], axis=1)[:, 0]
+            if write_mask is not None:  # retired slot: block may be reowned
+                phys = jnp.where(write_mask, phys, n_blocks)  # OOB: dropped
+            ckv = ckv.at[layer, phys, slot % block_len].set(lat[:, 0])
+            # the slots' ring views (sentinel pages clamp; masked below)
+            view = ckv[layer][pages].reshape(b, s_c, -1)
+        view = view[:, None]  # one KV "head": (B, 1, s_c, w)
+        lengths = jnp.minimum(pos + 1, s_c).astype(jnp.int32)
+    w_uk, w_uv = _mla_up(p, cfg, dt)
+    from repro.kernels import ops as kops
+
+    if use_kernel is None:
+        use_kernel = not kops.resolve_interpret()
+    scale = _mla_scale(cfg)
+    with jax.named_scope("mla.attend"):
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
+        q = _mla_pad(cfg, jnp.concatenate([q_lat, q_pe[:, 0]], axis=-1))
+        if use_kernel:
+            def latent(q, k, _, lens):  # values: k's first r columns
+                return kops.flash_decode(q, k, None, lens, v_width=r,
+                                         scale=scale)
+            o = _sharded(latent, mesh, q, view, view, lengths)
+        else:
+            from repro.kernels import ref as kref
+
+            o = kref.flash_decode_ref(q, view, view[..., :r], lengths,
+                                      scale=scale)
+    with jax.named_scope("mla.up"):
+        o = jnp.einsum("bhr,rhv->bhv", o.astype(dt), w_uv)
+        out = o.reshape(b, 1, -1) @ p["wo"].astype(dt)
+    return out, {"ckv": ckv}
 
 def _sharded(kernel, mesh, q, k_view, v_view, *lengths):
     """An attention kernel — queries ``(B, ..., Hq, hd)`` against

@@ -21,10 +21,23 @@ class ArchConfig:
     vocab: int
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0  # router outputs: every expert of the layer
     experts_per_token: int = 0
     moe_d_ff: int = 0  # per-expert FFN hidden (d_ff used if 0)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training layer; serving is dropless
+    n_local_experts: int = 0  # experts held here, [0, n); 0 = all
+    n_shared_experts: int = 0  # always-on experts, one SwiGLU of n * moe_d_ff
+    first_dense_layers: int = 0  # leading layers with a dense d_ff MLP
+    router_scoring: str = "softmax"  # softmax | sigmoid (DeepSeek-V3's
+    #   noaux_tc: top-k by score + a selection bias, gates from the score)
+    routed_scale: float = 1.0  # factor on the routed experts' gates
+
+    # --- attention kind ---
+    attn_kind: str = "gqa"  # gqa | mla (latent attention, DeepSeek-V2)
+    kv_lora_rank: int = 0  # mla: width of the cached latent c_kv
+    qk_nope_dim: int = 0  # mla: per-head query/key width without RoPE
+    qk_rope_dim: int = 0  # mla: width of the one rotary key all heads share
+    v_head_dim: int = 0  # mla: per-head value width
 
     # --- attention pattern ---
     window: int = 0  # sliding-window size; 0 = full attention
@@ -104,6 +117,14 @@ class ArchConfig:
         return self.n_experts > 0
 
     @property
+    def experts_held(self) -> int:
+        return self.n_local_experts or self.n_experts
+
+    @property
+    def latent_width(self) -> int:  # mla: [c_kv | k_pe], r + rope values
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
     def sub_quadratic(self) -> bool:
         """True if the arch supports long-context (500k) decode."""
         return self.layer_pattern in ("ssm", "griffin") or self.window > 0
@@ -125,6 +146,15 @@ class ArchConfig:
                 kinds.append("attn" if i % 3 == 2 else "rec")
             return kinds
         raise ValueError(self.layer_pattern)
+
+    def mlp_kinds(self) -> list[str]:
+        """Per-layer MLP kind: dense | moe | none (mamba blocks)."""
+        if self.layer_pattern == "ssm":
+            return ["none"] * self.n_layers
+        if not self.is_moe:
+            return ["dense"] * self.n_layers
+        return ["dense" if i < self.first_dense_layers else "moe"
+                for i in range(self.n_layers)]
 
     def scaled(self, **kw) -> "ArchConfig":
         """Reduced config of the same family (smoke tests)."""

@@ -1,7 +1,10 @@
 """Trunk assembly: scan-over-layers stacks of attn / moe / ssm / rec blocks.
 
-Layers are grouped into homogeneous *block groups* (e.g. Griffin's
-(rec, rec, attn) period) whose parameters are stacked along a leading
+A layer's kind is a pair (mixer, mlp): the mixer ``attn`` (GQA or MLA, as
+``cfg.attn_kind`` says), ``rec`` or ``ssm``; the MLP ``dense``, ``moe`` or
+``none`` (mamba blocks). Layers are grouped into homogeneous *block
+groups* (e.g. Griffin's (rec, rec, attn) period, or a leading dense layer
+then the expert layers) whose parameters are stacked along a leading
 layer axis and consumed by ``lax.scan`` — keeping HLO size (and therefore
 512-device compile time) independent of depth. Each scan step is wrapped in
 ``jax.checkpoint`` so only layer-boundary activations are saved (remat).
@@ -95,38 +98,47 @@ def _constrain_batch(x: jax.Array, mesh):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def block_groups(cfg: ArchConfig) -> list[tuple[tuple[str, ...], int]]:
-    """[(pattern, repeat)] covering cfg.layer_kinds()."""
-    kinds = cfg.layer_kinds()
+def block_groups(cfg: ArchConfig
+                 ) -> list[tuple[tuple[tuple[str, str], ...], int]]:
+    """[(pattern, repeat)] covering the layers' (mixer, mlp) kinds."""
+    kinds = list(zip(cfg.layer_kinds(), cfg.mlp_kinds()))
     if cfg.layer_pattern == "griffin":
-        period = ("rec", "rec", "attn")
+        period = tuple(kinds[:3])
         n_full = len(kinds) // 3
         groups = [(period, n_full)]
         rem = len(kinds) - 3 * n_full
         if rem:
             groups.append((tuple(kinds[3 * n_full :]), 1))
         return groups
-    return [((kinds[0],), len(kinds))]
+    groups: list[tuple[tuple[tuple[str, str], ...], int]] = []
+    for kind in kinds:  # runs of one kind
+        if groups and groups[-1][0] == (kind,):
+            groups[-1] = (groups[-1][0], groups[-1][1] + 1)
+        else:
+            groups.append(((kind,), 1))
+    return groups
 
 
 # ----------------------------------------------------------------- init
 
 
-def _init_one_layer(key, cfg: ArchConfig, kind: str) -> dict:
+def _init_one_layer(key, cfg: ArchConfig, kind: tuple[str, str]) -> dict:
     d = cfg.d_model
+    mixer, mlp = kind
     ks = jax.random.split(key, 4)
     p: dict[str, Any] = {"norm1": jnp.zeros((d,), jnp.float32)}
-    if kind == "attn":
+    if mixer == "attn":
         p["mix"] = attention.init(ks[0], cfg)
-    elif kind == "rec":
+    elif mixer == "rec":
         p["mix"] = rglru.init(ks[0], cfg)
-    elif kind == "ssm":
+    elif mixer == "ssm":
         p["mix"] = ssm.init(ks[0], cfg)
-        return p  # mamba blocks: norm + mixer only, no MLP
     else:
         raise ValueError(kind)
+    if mlp == "none":
+        return p  # mamba blocks: norm + mixer only, no MLP
     p["norm2"] = jnp.zeros((d,), jnp.float32)
-    if cfg.is_moe:
+    if mlp == "moe":
         p["mlp"] = moe.init(ks[1], cfg)
     else:
         p["mlp"] = mlp_init(ks[1], d, cfg.d_ff)
@@ -165,17 +177,18 @@ def init_params(key, cfg: ArchConfig) -> dict:
 def _apply_block(
     p: dict,
     cfg: ArchConfig,
-    kind: str,
+    kind: tuple[str, str],
     h: jax.Array,
     positions: jax.Array,
     prefix: int,
     mesh=None,
 ) -> tuple[jax.Array, jax.Array]:
+    mixer, mlp = kind
     aux = jnp.zeros((), jnp.float32)
     x = rms_norm(h, p["norm1"], cfg.norm_eps)
-    if kind == "ssm":
+    if mixer == "ssm":
         return h + ssm.forward(p["mix"], cfg, x), aux
-    if kind == "attn":
+    if mixer == "attn":
         win = _layer_window(cfg)
         mix = attention.forward(
             p["mix"], cfg, x, positions, window=win, prefix=prefix
@@ -184,7 +197,7 @@ def _apply_block(
         mix = rglru.forward(p["mix"], cfg, x)
     h = h + mix
     x = rms_norm(h, p["norm2"], cfg.norm_eps)
-    if cfg.is_moe:
+    if mlp == "moe":
         b, l, d = x.shape
         if mesh is not None and "model" in mesh.shape:
             out, aux = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d), mesh)
@@ -249,10 +262,24 @@ def apply_trunk(
 # ----------------------------------------------------------------- prefill
 
 
+def _serve_mlp(p: dict, cfg: ArchConfig, mlp: str, x: jax.Array, mesh):
+    """The MLP of a serving step (prefill or decode): an expert layer is
+    dropless here, so a token's output never depends on its batch mates."""
+    if mlp != "moe":
+        return swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
+    shape = x.shape
+    x = x.reshape(-1, shape[-1])
+    if mesh is not None and "model" in mesh.shape:
+        out, _ = moe.forward_dist(p["mlp"], cfg, x, mesh)
+    else:
+        out, _ = moe.forward(p["mlp"], cfg, x, dropless=True)
+    return out.reshape(shape)
+
+
 def _apply_block_prefill(
     p: dict,
     cfg: ArchConfig,
-    kind: str,
+    kind: tuple[str, str],
     h: jax.Array,
     positions: jax.Array,
     max_seq: int,
@@ -260,12 +287,13 @@ def _apply_block_prefill(
     mesh=None,
     lengths=None,
 ) -> tuple[jax.Array, dict]:
+    mixer, mlp = kind
     x = rms_norm(h, p["norm1"], cfg.norm_eps)
-    if kind == "ssm":
+    if mixer == "ssm":
         mix, cache = ssm.forward(p["mix"], cfg, x, return_cache=True,
                                  lengths=lengths)
         return h + mix, cache
-    if kind == "attn":
+    if mixer == "attn":
         win = _layer_window(cfg)
         with jax.named_scope("attention"):
             mix, cache = attention.prefill(
@@ -278,16 +306,7 @@ def _apply_block_prefill(
     h = h + mix
     x = rms_norm(h, p["norm2"], cfg.norm_eps)
     with jax.named_scope("mlp"):
-        if cfg.is_moe:
-            b, l, d = x.shape
-            if mesh is not None and "model" in mesh.shape:
-                out, _ = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d),
-                                          mesh)
-            else:
-                out, _ = moe.forward(p["mlp"], cfg, x.reshape(-1, d))
-            out = out.reshape(b, l, d)
-        else:
-            out = swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
+        out = _serve_mlp(p, cfg, mlp, x, mesh)
     return h + out, cache
 
 
@@ -356,13 +375,17 @@ def insert_cache_slots(
         raise ValueError("paged insert_cache_slots needs cfg")
     block_len = None
     for g_full, (pattern, _) in zip(full, block_groups(cfg)):
-        for j, kind in enumerate(pattern):
-            if kind == "attn":
-                block_len = g_full[str(j)]["k"].shape[3]
+        for j, (mixer, _) in enumerate(pattern):
+            if mixer == "attn":
+                block_len = jax.tree.leaves(g_full[str(j)])[0].shape[-2]
     assert block_len is not None, "paged insert on an attn-free arch"
     n_pages = pages.shape[1]
 
     def _scatter_attn(f, p):
+        if p.ndim == 4:  # (layers, Bn, s_c, w): MLA's latent, no head axis
+            lyr, bn, _, w = p.shape
+            pr = p.reshape(lyr, bn, n_pages, block_len, w)
+            return f.at[:, pages].set(pr.astype(f.dtype))
         # p: (layers, Bn, KV, s_c, hd) -> page-cut
         # (layers, Bn, n_pages, KV, block_len, hd) -> pool scatter
         lyr, bn, kv, _, hd = p.shape
@@ -373,9 +396,9 @@ def insert_cache_slots(
     out = []
     for g_full, g_part, (pattern, _) in zip(full, part, block_groups(cfg)):
         new_g = {}
-        for j, kind in enumerate(pattern):
+        for j, (mixer, _) in enumerate(pattern):
             f, p = g_full[str(j)], g_part[str(j)]
-            if kind == "attn":
+            if mixer == "attn":
                 new_g[str(j)] = jax.tree.map(_scatter_attn, f, p)
             else:
                 new_g[str(j)] = jax.tree.map(
@@ -391,6 +414,7 @@ def insert_cache_slots(
 
 def _block_cache(cfg: ArchConfig, kind: str, batch: int, max_seq: int, dtype,
                  paged: PagedLayout | None = None):
+    """The decode state of one layer whose mixer is ``kind``."""
     if kind == "attn":
         if paged is not None:
             paged.n_pages(cfg, max_seq)  # validate geometry
@@ -425,10 +449,13 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype,
     caches = []
     for pattern, count in block_groups(cfg):
         group = {}
-        for j, kind in enumerate(pattern):
-            one = _block_cache(cfg, kind, batch, max_seq, dtype, paged=paged)
+        for j, (mixer, _) in enumerate(pattern):
+            # zeros of the stacked shape made at once: a broadcast layer
+            # copied would hold the whole stack twice for a moment
+            one = jax.eval_shape(functools.partial(
+                _block_cache, cfg, mixer, batch, max_seq, dtype, paged=paged))
             group[str(j)] = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (count,) + a.shape).copy(), one
+                lambda a: jnp.zeros((count,) + a.shape, a.dtype), one
             )
         caches.append(group)
     return caches
@@ -437,39 +464,38 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype,
 def _apply_block_decode(
     p: dict,
     cfg: ArchConfig,
-    kind: str,
+    kind: tuple[str, str],
     h: jax.Array,  # (B, 1, d)
     cache: dict,
     pos: jax.Array,  # (B,)
     mesh=None,
     pages: jax.Array | None = None,
     write_mask: jax.Array | None = None,
+    layer=None,  # index into a cache stacked over the group (MLA)
 ) -> tuple[jax.Array, dict]:
+    mixer, mlp = kind
     x = rms_norm(h, p["norm1"], cfg.norm_eps)
-    if kind == "ssm":
+    if mixer == "ssm":
         mix, cache = ssm.decode(p["mix"], cfg, x, cache)
         return h + mix, cache
-    if kind == "attn":
+    if mixer == "attn":
         win = _layer_window(cfg)
         with jax.named_scope("attention"):
-            mix, cache = attention.decode(p["mix"], cfg, x, cache, pos,
-                                          window=win, pages=pages,
-                                          write_mask=write_mask, mesh=mesh)
+            if cfg.attn_kind == "mla":
+                mix, cache = attention.mla_decode(
+                    p["mix"], cfg, x, cache, pos, layer, pages=pages,
+                    write_mask=write_mask, mesh=mesh)
+            else:
+                mix, cache = attention.decode(p["mix"], cfg, x, cache, pos,
+                                              window=win, pages=pages,
+                                              write_mask=write_mask,
+                                              mesh=mesh)
     else:
         mix, cache = rglru.decode(p["mix"], cfg, x, cache)
     h = h + mix
     x = rms_norm(h, p["norm2"], cfg.norm_eps)
     with jax.named_scope("mlp"):
-        if cfg.is_moe:
-            b, _, d = x.shape
-            if mesh is not None and "model" in mesh.shape:
-                out, _ = moe.forward_dist(p["mlp"], cfg, x.reshape(-1, d),
-                                          mesh)
-            else:
-                out, _ = moe.forward(p["mlp"], cfg, x.reshape(-1, d))
-            out = out.reshape(b, 1, d)
-        else:
-            out = swiglu(x, p["mlp"]["w1"], p["mlp"]["w2"], p["mlp"]["w3"])
+        out = _serve_mlp(p, cfg, mlp, x, mesh)
     return h + out, cache
 
 
@@ -488,6 +514,26 @@ def apply_trunk_decode(
     for stack, cache, (pattern, count) in zip(
         params["blocks"], caches, block_groups(cfg)
     ):
+        if cfg.attn_kind == "mla":
+            # the latent cache rides the loop whole and each layer writes
+            # its row in place (attention.mla_decode): scanned over, it
+            # would be rebuilt as a second stack, the scan's output
+            def body_mla(carry, xs, pattern=pattern):
+                h, c = carry
+                layer_p, i = xs
+                c = dict(c)
+                for j, kind in enumerate(pattern):
+                    h, c[str(j)] = _apply_block_decode(
+                        layer_p[str(j)], cfg, kind, h, c[str(j)], pos,
+                        mesh=mesh, pages=pages, write_mask=write_mask,
+                        layer=i,
+                    )
+                return (h, c), None
+
+            (x, nc), _ = jax.lax.scan(body_mla, (x, cache),
+                                      (stack, jnp.arange(count)))
+            new_caches.append(nc)
+            continue
 
         def body(h, xs, pattern=pattern):
             layer_p, layer_c = xs

@@ -61,7 +61,7 @@ def test_smoke_decode_step(arch):
 
 @pytest.mark.parametrize(
     "arch", ["tinyllama-1.1b", "mixtral-8x22b", "mamba2-780m",
-             "recurrentgemma-9b"]
+             "recurrentgemma-9b", "moonlight-16b-a3b"]
 )
 def test_prefill_decode_parity(arch):
     """Hidden state from step-by-step decode must match the parallel
@@ -70,31 +70,38 @@ def test_prefill_decode_parity(arch):
     capacity dropping legitimately differs between batched forward
     (overflow drops) and one-token decode (never overflows)."""
     cfg = get_smoke(arch).scaled(head_mode="exact", capacity_factor=16.0)
-    m = Model(cfg)
+    # moonlight's sigmoid router picks its top-k in float32 from a bf16
+    # trunk's hidden state, which the expanded (forward) and absorbed
+    # (decode) MLA orders round differently: a near-tie then goes to
+    # another expert, a difference of order 1 in that token's state. In
+    # float32 the two paths agree to rounding, which is what this checks.
+    f32 = arch == "moonlight-16b-a3b"
+    m = Model(cfg, precision_policy="f32" if f32 else None)
     params = m.init(jax.random.key(1))
     l = 24
     toks = jax.random.randint(jax.random.key(2), (1, l), 0, cfg.vocab)
 
     from repro.models import transformer
-    from repro.models.layers import COMPUTE_DTYPE
 
-    x = params["embed"][toks].astype(COMPUTE_DTYPE)
+    dtype = m.compute_dtype
+    x = params["embed"][toks].astype(dtype)
     pos_full = jnp.broadcast_to(jnp.arange(l), (1, l))
     h_full, _ = transformer.apply_trunk(params, cfg, x, pos_full)
 
     cache = m.init_cache(1, 64)
+    step = jax.jit(lambda p, xt, c, t: transformer.apply_trunk_decode(
+        p, cfg, xt, c, t))
     hs = []
     for t in range(l):
-        xt = params["embed"][toks[:, t]][:, None].astype(COMPUTE_DTYPE)
-        ht, cache = transformer.apply_trunk_decode(
-            params, cfg, xt, cache, jnp.array([t], jnp.int32)
-        )
+        xt = params["embed"][toks[:, t]][:, None].astype(dtype)
+        ht, cache = step(params, xt, cache, jnp.array([t], jnp.int32))
         hs.append(ht[:, 0])
     h_dec = jnp.stack(hs, axis=1)
     # bf16 trunk: per-step rounding accumulates. recurrentgemma's RG-LRU
     # additionally reorders float ops (associative_scan prefill vs
-    # sequential decode), so it gets a little more slack.
-    tol = 0.12 if arch == "recurrentgemma-9b" else 0.08
+    # sequential decode), so it gets a little more slack. float32: the
+    # same sums in another order.
+    tol = 0.12 if arch == "recurrentgemma-9b" else 1e-4 if f32 else 0.08
     np.testing.assert_allclose(
         np.asarray(h_full, np.float32),
         np.asarray(h_dec, np.float32),
@@ -112,7 +119,7 @@ def test_prefill_decode_parity(arch):
 # reordering at ~1e-3 can flip an argmax tie. Its cache correctness is
 # covered by test_prefill_decode_parity (hidden-state corr > 0.999).
 @pytest.mark.parametrize("arch", ["mixtral-8x22b", "tinyllama-1.1b",
-                                  "mamba2-780m"])
+                                  "mamba2-780m", "moonlight-16b-a3b"])
 def test_prefill_matches_decode_continuation(arch):
     """prefill() then decode_step() must continue exactly like pure
     decode_step() from scratch."""
@@ -144,18 +151,19 @@ def test_prefill_matches_decode_continuation(arch):
 
 
 def test_skip_matrix_documented():
-    """The 40-cell grid matches DESIGN.md §4: 8 skips, 32 runnable."""
+    """The 44-cell grid matches DESIGN.md §4: 9 skips, 35 runnable."""
     skips = []
     for a in ARCHS:
         cfg = get(a)
         for s in SHAPES:
             if skip_reason(cfg, s):
                 skips.append((a, s))
-    assert len(skips) == 8, skips
+    assert len(skips) == 9, skips
     assert ("hubert-xlarge", "decode_32k") in skips
     assert ("hubert-xlarge", "long_500k") in skips
     for a in ["qwen3-moe-30b-a3b", "stablelm-3b", "granite-8b",
-              "tinyllama-1.1b", "starcoder2-3b", "paligemma-3b"]:
+              "tinyllama-1.1b", "starcoder2-3b", "paligemma-3b",
+              "moonlight-16b-a3b"]:
         assert (a, "long_500k") in skips
     for a in ["mixtral-8x22b", "mamba2-780m", "recurrentgemma-9b"]:
         assert not skip_reason(get(a), "long_500k")

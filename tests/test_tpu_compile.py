@@ -6,7 +6,7 @@ described ``v5e:2x2`` topology — no chip attached — so a block spec the
 chip would refuse fails here. Widths are TinyLlama-1.1B serving with the
 amortized IVF head: vocabulary 32000, d 2048, 178 clusters of capacity 544,
 8 probes, k = l = 576 (tail buffer 728), 32/4 heads of 64 on a 256-long
-bf16 cache, 4 slots.
+bf16 cache, 4 slots; and the latent decode at Moonlight-16B-A3B's widths.
 
 The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and test workers import every file.
@@ -92,6 +92,20 @@ def test_flash_decode_compiles(one_chip):
         lambda *a: flash_decode.flash_decode(*a, interpret=False),
         ((SLOTS, HQ, HD), BF16), ((SLOTS, HKV, S, HD), BF16),
         ((SLOTS, HKV, S, HD), BF16), ((SLOTS,), I32),
+    )
+
+
+def test_flash_decode_latent_compiles(one_chip):
+    """MLA's absorbed decode at Moonlight-16B-A3B's widths: 16 query heads
+    on one latent "head" 640 wide (512 + 64 zero-padded to 128s), values
+    its first 512 columns, 64 slots of a 2048-long bf16 ring."""
+    _compile(
+        one_chip,
+        lambda q, c, n: flash_decode.flash_decode(
+            q, c, None, n, interpret=False, v_width=512, scale=192**-0.5),
+        ((64, 16, 640), jnp.bfloat16),
+        ((64, 1, 2048, 640), jnp.bfloat16),
+        ((64,), jnp.int32),
     )
 
 
